@@ -91,6 +91,32 @@ class PTQReport:
                 f"rel_err mean={self.mean_rel_err:.2e} max={self.max_rel_err:.2e}")
 
 
+def _policy_fmt(policy: QuantPolicy):
+    """The fp8 storage format of ``policy`` (None = its int8 scheme)."""
+    if policy.fmt == "int8":
+        return None
+    return quant.E4M3 if policy.fmt == "e4m3" else quant.E5M2
+
+
+def _quantize_as(leaf, kind: str, policy: QuantPolicy, fmt
+                 ) -> Tuple[QuantizedTensor, str]:
+    """Quantize one leaf under the scheme ``policy.match`` chose; returns
+    the tensor and the scheme actually applied.  Every scheme reduces over
+    the last two dims only, so each leading index (a stacked layer, an
+    expert) quantizes on its own."""
+    if fmt is None or kind == "int8":
+        # int8: per-channel everywhere (block int8 unneeded) — either the
+        # policy-wide fmt or a per-group "int8" override.  The report
+        # records the scheme actually applied, not the pattern-list kind
+        # (a block-matched group under fmt="int8" used to be mislabeled
+        # "block" while per-channel int8 was what ran).
+        return quant.quantize_per_channel_int8(leaf, contract_axis=-2), "int8"
+    if kind == "block":
+        return (quant.quantize_blockwise(leaf, block=policy.block, fmt=fmt),
+                "block")
+    return quant.quantize_per_channel(leaf, contract_axis=-2, fmt=fmt), "linear"
+
+
 def quantize_params(
     params: Any,
     policy: QuantPolicy = PAPER_POLICY,
@@ -103,17 +129,16 @@ def quantize_params(
     Returns the quantized pytree (and a :class:`PTQReport` when
     ``with_report=True``).  ``compute_errors`` additionally measures the
     per-tensor relative L2 quantization error (costs one dequantize each).
+    Leaves that already are :class:`QuantizedTensor` pass through, so a
+    tree from :func:`build_quantized_params` is returned as it is.
     """
-    if policy.fmt == "int8":
-        fmt = None  # symmetric int8 path
-    else:
-        fmt = quant.E4M3 if policy.fmt == "e4m3" else quant.E5M2
+    fmt = _policy_fmt(policy)
     report = PTQReport()
 
     def _maybe_quantize(path, leaf):
-        if not isinstance(leaf, (jax.Array, np.ndarray)) or not hasattr(leaf, "ndim"):
-            return leaf
         if isinstance(leaf, QuantizedTensor):
+            return leaf
+        if not isinstance(leaf, (jax.Array, np.ndarray)) or not hasattr(leaf, "ndim"):
             return leaf
         if not jnp.issubdtype(leaf.dtype, jnp.floating):
             return leaf
@@ -121,20 +146,7 @@ def quantize_params(
         kind, pattern = policy.match(p, leaf.ndim, leaf.shape)
         if kind is None:
             return leaf
-        if fmt is None or kind == "int8":
-            # int8: per-channel everywhere (block int8 unneeded) — either the
-            # policy-wide fmt or a per-group "int8" override.  The report
-            # records the scheme actually applied, not the pattern-list kind
-            # (a block-matched group under fmt="int8" used to be mislabeled
-            # "block" while per-channel int8 was what ran).
-            q = quant.quantize_per_channel_int8(leaf, contract_axis=-2)
-            applied = "int8"
-        elif kind == "block":
-            q = quant.quantize_blockwise(leaf, block=policy.block, fmt=fmt)
-            applied = "block"
-        else:
-            q = quant.quantize_per_channel(leaf, contract_axis=-2, fmt=fmt)
-            applied = "linear"
+        q, applied = _quantize_as(leaf, kind, policy, fmt)
         q.tag = p  # key for activation-amax capture / static-scale attach
         if with_report:
             err = float(quant.quant_error(leaf, q)) if compute_errors else float("nan")
@@ -144,10 +156,57 @@ def quantize_params(
                        granularity=q.granularity, pattern=pattern)
         return q
 
-    quantized = jax.tree_util.tree_map_with_path(_maybe_quantize, params)
+    quantized = jax.tree_util.tree_map_with_path(
+        _maybe_quantize, params,
+        is_leaf=lambda x: isinstance(x, QuantizedTensor))
     if with_report:
         return quantized, report
     return quantized
+
+
+def quantized_leaf_programs(init_fn: Callable[[jax.Array], Any], key,
+                            policy: QuantPolicy):
+    """One jitted program per leaf of the tree ``init_fn(key)`` builds.
+
+    Program i returns leaf i alone, already quantized under ``policy``:
+    XLA drops the random-number work of every other leaf, and a leaf with
+    leading (stacked-layer) axes is quantized one leading index at a time.
+    So no program holds more than one high-precision leaf, and the leaves
+    equal those of ``quantize_params(init_fn(key), policy)`` run under one
+    ``jax.jit`` bit for bit.
+    ``key`` may be an array or a ``ShapeDtypeStruct`` (ahead-of-time
+    compiles).  Returns ``(treedef, [(path, program)])``."""
+    fmt = _policy_fmt(policy)
+    shapes = jax.eval_shape(init_fn, key)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    programs = []
+    for i, (path, leaf) in enumerate(flat):
+        p = _path_str(path)
+        kind = None
+        if jnp.issubdtype(leaf.dtype, jnp.floating):
+            kind, _ = policy.match(p, leaf.ndim, leaf.shape)
+
+        def program(k, i=i, kind=kind, p=p):
+            w = jax.tree_util.tree_leaves(init_fn(k))[i]
+            if kind is None:
+                return w
+            one = lambda x: _quantize_as(x, kind, policy, fmt)[0]
+            q = jax.lax.map(one, w) if w.ndim > 2 else one(w)
+            q.tag = p
+            return q
+
+        programs.append((p, jax.jit(program)))
+    return treedef, programs
+
+
+def build_quantized_params(init_fn: Callable[[jax.Array], Any], key,
+                           policy: QuantPolicy) -> Any:
+    """``quantize_params(init_fn(key), policy)``, built one leaf at a time
+    (:func:`quantized_leaf_programs`): the device never holds the
+    high-precision tree next to the quantized one, only one leaf of it."""
+    treedef, programs = quantized_leaf_programs(init_fn, key, policy)
+    return jax.tree_util.tree_unflatten(
+        treedef, [program(key) for _, program in programs])
 
 
 def dequantize_params(params: Any, dtype=jnp.bfloat16) -> Any:

@@ -43,9 +43,10 @@ NEG_INF = -2.0e38
 def _decode_kernel(tabs_ref, len_ref, st_ref, *refs, scale: float,
                    page_size: int, group: int, n_p: int, branch_stride: int,
                    quantized: bool, out_dtype):
-    """Blocks: q (1,1,CG,hd); k/v (ps,1,hd) at physical page tab[b,p];
-    pos (1,ps); [k/v scales (ps,1)]; o (1,1,CG,hd); scratch m/l (CG,1) f32,
-    acc (CG,hd) f32.  Rows fold (branch, group-head): r = c * group + g."""
+    """Blocks: q (1,1,CG,hd); k/v (ps,hd) — KV head h's lanes of physical
+    page tab[b,p]; pos (1,1,ps); [k/v scales (ps,Kv), all heads of the
+    page]; o (1,1,CG,hd); scratch m/l (CG,1) f32, acc (CG,hd) f32.  Rows
+    fold (branch, group-head): r = c * group + g."""
     if quantized:
         (q_ref, k_ref, v_ref, pos_ref, ks_ref, vs_ref,
          o_ref, m_ref, l_ref, acc_ref) = refs
@@ -53,6 +54,7 @@ def _decode_kernel(tabs_ref, len_ref, st_ref, *refs, scale: float,
         q_ref, k_ref, v_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref = refs
         ks_ref = vs_ref = None
     b = pl.program_id(0)
+    h = pl.program_id(1)
     p_idx = pl.program_id(2)
 
     @pl.when(p_idx == 0)
@@ -61,15 +63,20 @@ def _decode_kernel(tabs_ref, len_ref, st_ref, *refs, scale: float,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    cg, hd = q_ref.shape[2], q_ref.shape[3]
+    cg = q_ref.shape[2]
     q = q_ref[0, 0]                                        # (CG, hd)
-    k = k_ref[:, 0, :]                                     # (ps, hd)
-    v = v_ref[:, 0, :]
+    k = k_ref[...]                                         # (ps, hd)
+    v = v_ref[...]
     if quantized:
         # in-register dequant, bit-compatible with core.quant.dequantize_kv:
-        # f32 payload x per-(position, head) scale, cast to the compute dtype
-        k = (k.astype(jnp.float32) * ks_ref[:, 0][:, None]).astype(q.dtype)
-        v = (v.astype(jnp.float32) * vs_ref[:, 0][:, None]).astype(q.dtype)
+        # f32 payload x per-(position, head) scale, cast to the compute
+        # dtype.  The scale block holds every KV head of the page; head h's
+        # column is picked with a one-hot lane sum (exact: one term)
+        head = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape, 1) == h
+        ks = jnp.sum(jnp.where(head, ks_ref[...], 0.0), axis=1, keepdims=True)
+        vs = jnp.sum(jnp.where(head, vs_ref[...], 0.0), axis=1, keepdims=True)
+        k = (k.astype(jnp.float32) * ks).astype(q.dtype)
+        v = (v.astype(jnp.float32) * vs).astype(q.dtype)
     elif k.dtype != q.dtype:
         k = k.astype(q.dtype)
         v = v.astype(q.dtype)
@@ -82,15 +89,14 @@ def _decode_kernel(tabs_ref, len_ref, st_ref, *refs, scale: float,
     # covers logical span [p*ps, (p+1)*ps), whatever physical page it maps
     length = len_ref[b]
     start = st_ref[b]
-    posv = pos_ref[0]                                      # (ps,) stored pos
+    posv = pos_ref[0]                                      # (1, ps) stored pos
     logical = p_idx * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)                      # (1, ps)
     c_idx = jax.lax.broadcasted_iota(jnp.int32, (cg, 1), 0) // group
     own_lo = start + c_idx * branch_stride                 # (CG, 1)
     shared = logical < start
     own = (logical >= own_lo) & (logical < own_lo + branch_stride)
-    valid = ((posv[None, :] >= 0) & (posv[None, :] <= length)
-             & (shared | own))                             # (CG, ps)
+    valid = ((posv >= 0) & (posv <= length) & (shared | own))  # (CG, ps)
     scores = jnp.where(valid, scores, NEG_INF)
 
     m_old = m_ref[...]
@@ -120,34 +126,42 @@ def paged_decode_pallas(q, k, v, pos, k_scale, v_scale, tables, lengths,
     sentinel page last); pos (NPos // page_size, page_size); k_scale /
     v_scale (NPos, Kv) f32 or None (BF16 pool); tables (B, P) int32
     physical page per logical entry (sentinel = unmapped); lengths/starts
-    (B,) int32."""
+    (B,) int32.
+
+    Every block's last two dims must tile the TPU's (8, 128) vreg or span
+    the whole array dim, so the kernel sees views of the same buffers:
+    k/v as (NPos, Kv*hd) — block (ps, hd) at (page, head) — pos as
+    (pages, 1, ps), and the scales as (ps, Kv) blocks of whole pages."""
     bb, kv, cg, hd = q.shape
     n_p = tables.shape[1]
     quantized = k_scale is not None
     grid = (bb, kv, n_p)
+    k = k.reshape(k.shape[0], kv * hd)
+    v = v.reshape(v.shape[0], kv * hd)
+    pos = pos.reshape(pos.shape[0], 1, page_size)
 
     def _q_map(b, h, p, tabs, lens, sts):
         return (b, h, 0, 0)
 
     def _kv_map(b, h, p, tabs, lens, sts):
-        return (tabs[b, p], h, 0)
+        return (tabs[b, p], h)
 
     def _pos_map(b, h, p, tabs, lens, sts):
-        return (tabs[b, p], 0)
+        return (tabs[b, p], 0, 0)
 
     def _scale_map(b, h, p, tabs, lens, sts):
-        return (tabs[b, p], h)
+        return (tabs[b, p], 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, cg, hd), _q_map),
-        pl.BlockSpec((page_size, 1, hd), _kv_map),
-        pl.BlockSpec((page_size, 1, hd), _kv_map),
-        pl.BlockSpec((1, page_size), _pos_map),
+        pl.BlockSpec((page_size, hd), _kv_map),
+        pl.BlockSpec((page_size, hd), _kv_map),
+        pl.BlockSpec((1, 1, page_size), _pos_map),
     ]
     args = [q, k, v, pos]
     if quantized:
-        in_specs += [pl.BlockSpec((page_size, 1), _scale_map),
-                     pl.BlockSpec((page_size, 1), _scale_map)]
+        in_specs += [pl.BlockSpec((page_size, kv), _scale_map),
+                     pl.BlockSpec((page_size, kv), _scale_map)]
         args += [k_scale, v_scale]
     return pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, page_size=page_size,

@@ -18,15 +18,51 @@ hold-window admission policy targets.  Without it, the closed-batch
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import registry
+from repro.configs.base import OneRecConfig
+from repro.core.ptq import build_quantized_params
 from repro.models import onerec as onerec_model
 from repro.serving import EngineConfig, ServingEngine, run_open_loop
+from repro.serving.engine import resolve_quant_policy
 from repro.serving.requests import build_requests  # noqa: F401  (re-export:
 #                        the benches and examples used to import it here)
+
+# the checkout this module runs from (src/repro/launch/serve.py)
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on for this process and
+    return its directory: ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX
+    reads the variable itself, so nothing is set here), else the fixed
+    ``<checkout>/.jax_cache`` — fixed, because the path is part of every
+    cache key.  Called by the entry points only, never on import."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_engine(cfg: OneRecConfig, engine_cfg: EngineConfig,
+                 seed: int = 0) -> ServingEngine:
+    """The served engine: random weights from ``seed``, initialised in
+    the bf16 compute dtype and quantized under the engine's policy one
+    leaf at a time, so the device never holds the high-precision tree
+    next to the served one (at ``CONFIG`` widths the f32 tree alone
+    exceeds a 16 GB chip)."""
+    policy, _ = resolve_quant_policy(engine_cfg)
+    params = build_quantized_params(
+        lambda k: onerec_model.init_onerec(k, cfg, jnp.bfloat16),
+        jax.random.PRNGKey(seed), policy)
+    return ServingEngine(params, cfg, engine_cfg)
 
 
 def main():
@@ -131,8 +167,8 @@ def main():
     mod = registry.get_arch("onerec-v2")
     cfg = mod.reduced_config() if args.reduced else mod.CONFIG
     batch = args.batch or cfg.serve_batch
-    params = onerec_model.init_onerec(jax.random.PRNGKey(args.seed), cfg)
-    engine = ServingEngine(params, cfg, EngineConfig(
+    enable_compile_cache()
+    engine = build_engine(cfg, EngineConfig(
         batch_size=batch, use_fp8=args.fp8, mode=args.mode,
         kv_dtype="float8_e4m3fn" if args.kv_fp8 else "bfloat16",
         n_slots=args.slots, max_queue=args.max_queue,
@@ -142,7 +178,8 @@ def main():
         prefill_chunk=args.prefill_chunk, preemption=args.preemption,
         max_candidates=args.n_candidates,
         paged=args.paged, page_size=args.page_size, n_pages=args.pages,
-        fused_decode=args.fused_decode, quant_policy=args.quant_policy))
+        fused_decode=args.fused_decode, quant_policy=args.quant_policy),
+        args.seed)
     requests = build_requests(cfg, args.requests, batch, args.seed,
                               args.ragged, n_candidates=args.n_candidates)
 

@@ -39,7 +39,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.configs.base import OneRecConfig
-from repro.core.policy import QuantPolicy, load_policy_artifact
+from repro.core.policy import (BASELINE_POLICY, PAPER_POLICY, QuantPolicy,
+                               load_policy_artifact)
 from repro.serving.executor import PhaseExecutor
 from repro.serving.kv_cache import PrefixStore, SlotPool
 from repro.serving.requests import requests_from_arrays
@@ -122,6 +123,26 @@ class EngineConfig:
     #                                autotune artifact JSON (loaded with its
     #                                calibrated static act scales); overrides
     #                                the all-or-nothing use_fp8 switch
+
+
+def resolve_quant_policy(engine_cfg: EngineConfig
+                         ) -> Tuple[QuantPolicy, Optional[Dict[str, float]]]:
+    """The weight policy an engine serves, and any calibrated static
+    activation scales: a str ``quant_policy`` is an autotune artifact path
+    (policy + scales travel together), a ``QuantPolicy`` applies as-is,
+    and None falls back to the all-or-nothing ``use_fp8`` switch."""
+    quant_policy, act_scales = engine_cfg.quant_policy, None
+    if isinstance(quant_policy, str):
+        artifact = load_policy_artifact(quant_policy)
+        quant_policy = artifact["policy"]
+        act_scales = artifact.get("act_scales") or None
+    elif quant_policy is None:
+        quant_policy = PAPER_POLICY if engine_cfg.use_fp8 else BASELINE_POLICY
+    elif not isinstance(quant_policy, QuantPolicy):
+        raise ValueError(
+            f"quant_policy must be a QuantPolicy or an artifact path, "
+            f"got {type(quant_policy).__name__}")
+    return quant_policy, act_scales
 
 
 class RequestHandle:
@@ -248,19 +269,7 @@ class ServingEngine:
             n_pages = engine_cfg.n_pages or \
                 -(-(self.n_slots + prefix_rows) * s_row
                   // engine_cfg.page_size)
-        # tuned mixed-precision policy: a str is an autotune artifact path
-        # (policy + calibrated static act scales travel together); a
-        # QuantPolicy instance applies as-is
-        quant_policy, act_scales = engine_cfg.quant_policy, None
-        if isinstance(quant_policy, str):
-            artifact = load_policy_artifact(quant_policy)
-            quant_policy = artifact["policy"]
-            act_scales = artifact.get("act_scales") or None
-        elif quant_policy is not None \
-                and not isinstance(quant_policy, QuantPolicy):
-            raise ValueError(
-                f"quant_policy must be a QuantPolicy or an artifact path, "
-                f"got {type(quant_policy).__name__}")
+        quant_policy, act_scales = resolve_quant_policy(engine_cfg)
         self.executor = PhaseExecutor(
             params, cfg, n_slots=self.n_slots, use_fp8=engine_cfg.use_fp8,
             topk=engine_cfg.topk, use_radix_topk=engine_cfg.use_radix_topk,

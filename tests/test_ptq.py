@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (BASELINE_POLICY, PAPER_POLICY, QuantizedTensor,
                         dequantize_params, is_quantized, quantize_params)
@@ -134,3 +135,30 @@ def test_int8_override_on_one_group():
     assert kinds[ok] == "linear"
     assert by_path[ok].data.dtype == jnp.float8_e4m3fn
     assert kinds["stacks/0/p0/moe/experts/gate"] == "block"
+
+
+@pytest.mark.parametrize("policy", [PAPER_POLICY, BASELINE_POLICY,
+                                    PAPER_POLICY.replace(fmt="int8")],
+                         ids=["fp8", "baseline", "int8"])
+def test_leaf_at_a_time_build_matches_quantize_params(policy):
+    """The served-param builder (one program per leaf, stacked leaves
+    quantized one layer at a time) equals init + PTQ under one jit bit
+    for bit, tags included, and PTQ of its output is a no-op."""
+    from repro.configs import registry
+    from repro.core.ptq import build_quantized_params
+    from repro.models import onerec
+
+    cfg = registry.get_arch("onerec-v2").reduced_config()
+    init = lambda k: onerec.init_onerec(k, cfg, jnp.bfloat16)
+    key = jax.random.PRNGKey(3)
+    built = build_quantized_params(init, key, policy)
+    ref = jax.jit(lambda k: quantize_params(init(k), policy))(key)
+    leaves, treedef = jax.tree_util.tree_flatten(built)
+    ref_leaves, ref_treedef = jax.tree_util.tree_flatten(ref)
+    assert treedef == ref_treedef
+    for a, b in zip(leaves, ref_leaves):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    again = jax.tree_util.tree_leaves(quantize_params(built, policy))
+    assert all(x is y for x, y in zip(again, leaves))
