@@ -179,4 +179,6 @@ def paged_decode_pallas(q, k, v, pos, k_scale, v_scale, tables, lengths,
             ]),
         out_shape=jax.ShapeDtypeStruct((bb, kv, cg, hd), out_dtype),
         interpret=interpret,
+        # a stable kernel name in profiler traces (paged_decode_roofline)
+        name="paged_decode",
     )(tables, lengths, starts, *args)

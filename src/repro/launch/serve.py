@@ -247,6 +247,11 @@ def main():
           f"p99={stats['join_p99_s']*1e3:.1f}ms, "
           f"decode-stall {100*stats['decode_stall_frac']:.0f}% of wall) | "
           f"preemptions={int(stats['preemptions'])}")
+    print(f"[serve] request p50 split: "
+          f"queue wait={stats['queue_wait_p50_s']*1e3:.1f}ms "
+          f"prefill phase={stats['prefill_phase_p50_s']*1e3:.1f}ms "
+          f"decode phase={stats['decode_phase_p50_s']*1e3:.1f}ms | "
+          f"host time per step={stats['host_s_per_step']*1e3:.2f}ms")
     if args.n_candidates > 1:
         print(f"[serve] multi-candidate: K={args.n_candidates} | "
               f"tree-decode programs "

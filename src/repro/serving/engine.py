@@ -22,6 +22,10 @@ of handing over a closed batch:
   * ``stats()`` / ``reset_window()`` — windowed metrics over whatever the
     caller defines as one measurement.
 
+Each ``step()`` is a ``serve.step`` span (``serving/spans.py``): with a
+``jax.profiler`` trace running, the round's host spans land in the trace
+beside the device ops; without one they only feed the window counters.
+
 ``serve_requests`` / ``generate_batch`` — the seed engine's closed-batch
 API — are thin shims implemented PURELY in terms of submit + step + drain
 (token-identical to the closed-loop scheduler they replaced), and
@@ -47,6 +51,7 @@ from repro.serving.requests import requests_from_arrays
 from repro.serving.scheduler import (Completion, ContinuousScheduler,
                                      FixedBatchScheduler, Request,
                                      SchedulingPolicy)
+from repro.serving.spans import span
 
 
 class AdmissionFull(RuntimeError):
@@ -310,10 +315,8 @@ class ServingEngine:
         self._sched = self._make_scheduler(self.pool)
         self._rids = itertools.count()
         self._handles: Dict[int, RequestHandle] = {}
-        # windowed per stats window (kept as an attribute for compatibility
-        # with the seed engine's A/B scripts)
-        self.metrics: Dict[str, List[float]] = {"latency_s": [],
-                                                "batch_size": []}
+        # per-request latencies of the last serve_requests call
+        self.metrics: Dict[str, List[float]] = {"latency_s": []}
         self.reset_window()
 
     def _make_scheduler(self, pool: SlotPool):
@@ -399,8 +402,18 @@ class ServingEngine:
 
     def step(self) -> List[Completion]:
         """Advance the scheduler one round and deliver completions to
-        their handles.  Non-blocking; an idle engine no-ops."""
-        done = self._sched.step()
+        their handles.  Non-blocking; an idle engine no-ops.  A round that
+        dispatched a device program samples its host time: the
+        ``serve.step`` span less the time spent inside ``serve.device_wait``
+        and ``serve.select`` (``stats()["host_s_per_step"]``)."""
+        n = self.executor.counters
+        programs = n["prefill_calls"] + n["decode_steps"]
+        waited = n["serve.device_wait"] + n["serve.select"]
+        with span("serve.step") as round_:
+            done = self._sched.step()
+        if n["prefill_calls"] + n["decode_steps"] > programs:
+            self._host_s.append(round_.s - (n["serve.device_wait"]
+                                            + n["serve.select"] - waited))
         for c in done:
             handle = self._handles.pop(c.rid, None)
             if handle is not None:
@@ -477,6 +490,7 @@ class ServingEngine:
             self.executor.counters[k] = 0
         self._sched.reset_window()
         self._window_done: List[Completion] = []
+        self._host_s: List[float] = []
         self._rejected = 0
         self._cancelled = 0
         self._window_t0 = time.perf_counter()
@@ -492,6 +506,7 @@ class ServingEngine:
         counters = self.executor.counters
         lat = np.asarray([c.latency_s for c in done], np.float64)
         join = np.asarray(sched.join_step_s, np.float64)
+        median = lambda xs: float(np.median(xs)) if len(xs) else 0.0
         return {
             "n_requests": float(len(done)),
             "wall_s": wall,
@@ -533,9 +548,7 @@ class ServingEngine:
             "rejected": float(self._rejected),
             "cancelled": float(self._cancelled),
             "hold_rounds": float(sched.holds),
-            "queue_depth": float(sched.queue_depth),
-            # prefill waste: batch padding (rows) + bucket padding (tokens)
-            "prefill_padded_rows": float(counters["prefill_padded_rows"]),
+            # prefill waste: bucket padding (tokens)
             "prefill_tokens": float(counters["prefill_tokens_batched"]),
             "prefill_padded_token_frac":
                 1.0 - counters["prefill_tokens_real"]
@@ -545,12 +558,20 @@ class ServingEngine:
             # (chunked prefill bounds its tail); decode-stall = the share of
             # the window's wall clock decoders spent waiting on that work
             "join_steps": float(join.size),
-            "join_mean_s": float(join.mean()) if join.size else 0.0,
             "join_p50_s": float(np.percentile(join, 50))
             if join.size else 0.0,
             "join_p99_s": float(np.percentile(join, 99))
             if join.size else 0.0,
             "decode_stall_frac": sched.decode_stall_s / wall if wall else 0.0,
+            # where a request's latency goes (lifecycle stamps): queue wait
+            # over the requests first admitted in the window, prefill and
+            # decode phases over those completed in it; and the host time
+            # of a round that dispatched a device program
+            "queue_wait_p50_s": median(sched.queue_wait_s),
+            "prefill_phase_p50_s": median([c.prefill_phase_s for c in done]),
+            "decode_phase_p50_s": median([c.decode_phase_s for c in done]),
+            "host_s_per_step": float(np.mean(self._host_s))
+            if self._host_s else 0.0,
             "preemptions": float(sched.preemptions),
             **self._sla_stats(done),
             **self._prefix_stats(),
@@ -615,7 +636,6 @@ class ServingEngine:
 
         outputs = [h.completion.item for h in handles]
         self.metrics["latency_s"] = [h.completion.latency_s for h in handles]
-        self.metrics["batch_size"] = [float(len(requests))]
         return outputs, self._stats(wall)
 
     @staticmethod

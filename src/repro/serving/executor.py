@@ -61,6 +61,7 @@ from repro.core.ptq import apply_static_act_scales, quantize_params
 from repro.models import onerec as onerec_model
 from repro.models import transformer as tfm_model
 from repro.serving.kv_cache import INDEX_DTYPE, PagePool, as_index
+from repro.serving.spans import span
 
 logger = logging.getLogger(__name__)
 
@@ -215,12 +216,16 @@ class PhaseExecutor:
                                          "fused_decode_steps": 0,
                                          "fused_select_hits": 0,
                                          "select_calls": 0,
-                                         "prefill_padded_rows": 0,
                                          "prefill_tokens_batched": 0,
                                          "prefill_tokens_real": 0,
                                          "prefix_row_copies": 0,
                                          "cow_copies": 0,
-                                         "pages_granted": 0}
+                                         "pages_granted": 0,
+                                         # host seconds inside the spans
+                                         # a round spends waiting on the
+                                         # device (host_s_per_step)
+                                         "serve.device_wait": 0.0,
+                                         "serve.select": 0.0}
         # NOTE: every phase entry point below gates on completion via
         # block_until_ready before returning, so async dispatch can't smear
         # one phase's device work into the next host-side measurement — the
@@ -494,7 +499,6 @@ class PhaseExecutor:
             tok[i, :lens[j]] = tokens_list[j]
             lengths[i] = lens[j]
         self.counters["prefill_calls"] += 1
-        self.counters["prefill_padded_rows"] += b_bucket - n
         self.counters["prefill_tokens_batched"] += b_bucket * t_bucket
         self.counters["prefill_tokens_real"] += sum(lens)
         return tok, lengths, src
@@ -625,26 +629,29 @@ class PhaseExecutor:
         the bucket shape here means downstream ``select`` compiles once per
         power-of-two bucket, not once per join-group size.
         """
-        tok, lengths, src = self._pad_group(tokens_list)
-        prof = np.stack([profiles[j] for j in src]).astype(np.float32)
-        slot_ids = np.asarray([slots[j] for j in src], np.int32)
-        if self.paged:
-            # scatter each row's occupancy (profile + history) onto its
-            # granted pages; duplicate padded rows write identical values
-            t_eff = tok.shape[1] + 1
-            logical = np.broadcast_to(
-                np.arange(t_eff, dtype=INDEX_DTYPE)[None, :],
-                (tok.shape[0], t_eff))
-            valid = logical < (as_index(lengths)[:, None] + 1)
-            psc = self._scatter_indices(slot_ids, logical, valid)
-            logits, self.cache = self._prefill_insert_paged(
-                self.params, self.cache, jnp.asarray(tok),
-                jnp.asarray(prof), jnp.asarray(lengths), jnp.asarray(psc))
-        else:
-            logits, self.cache = self._prefill_insert(
-                self.params, self.cache, jnp.asarray(tok), jnp.asarray(prof),
-                jnp.asarray(lengths), jnp.asarray(slot_ids))
-        logits.block_until_ready()
+        with span("serve.stage"):
+            tok, lengths, src = self._pad_group(tokens_list)
+            prof = np.stack([profiles[j] for j in src]).astype(np.float32)
+            slot_ids = np.asarray([slots[j] for j in src], np.int32)
+            if self.paged:
+                # scatter each row's occupancy (profile + history) onto its
+                # granted pages; duplicate padded rows write identical values
+                t_eff = tok.shape[1] + 1
+                logical = np.broadcast_to(
+                    np.arange(t_eff, dtype=INDEX_DTYPE)[None, :],
+                    (tok.shape[0], t_eff))
+                valid = logical < (as_index(lengths)[:, None] + 1)
+                psc = self._scatter_indices(slot_ids, logical, valid)
+                logits, self.cache = self._prefill_insert_paged(
+                    self.params, self.cache, jnp.asarray(tok),
+                    jnp.asarray(prof), jnp.asarray(lengths),
+                    jnp.asarray(psc))
+            else:
+                logits, self.cache = self._prefill_insert(
+                    self.params, self.cache, jnp.asarray(tok),
+                    jnp.asarray(prof), jnp.asarray(lengths),
+                    jnp.asarray(slot_ids))
+        self._device_wait(logits)
         return logits
 
     def resume_prefill(self, tokens_list: List[np.ndarray],
@@ -658,27 +665,28 @@ class PhaseExecutor:
         Same bucketing/padding contract as ``prefill_insert``; returns
         full-bucket next-token logits.
         """
-        tok, lengths, src = self._pad_group(tokens_list)
-        start_arr = np.asarray([starts[j] for j in src], np.int32)
-        slot_ids = np.asarray([slots[j] for j in src], np.int32)
-        if self.paged:
-            t = tok.shape[1]
-            logical = (start_arr[:, None].astype(INDEX_DTYPE)
-                       + np.arange(t, dtype=INDEX_DTYPE)[None, :])
-            valid = (np.arange(t, dtype=INDEX_DTYPE)[None, :]
-                     < as_index(lengths)[:, None])
-            psc = self._scatter_indices(slot_ids, logical, valid)
-            pgi = self._gather_indices(slot_ids)
-            logits, self.cache = self._resume_prefill_paged(
-                self.params, self.cache, jnp.asarray(tok),
-                jnp.asarray(lengths), jnp.asarray(start_arr),
-                jnp.asarray(psc), jnp.asarray(pgi))
-        else:
-            logits, self.cache = self._resume_prefill(
-                self.params, self.cache, jnp.asarray(tok),
-                jnp.asarray(lengths), jnp.asarray(start_arr),
-                jnp.asarray(slot_ids))
-        logits.block_until_ready()
+        with span("serve.stage"):
+            tok, lengths, src = self._pad_group(tokens_list)
+            start_arr = np.asarray([starts[j] for j in src], np.int32)
+            slot_ids = np.asarray([slots[j] for j in src], np.int32)
+            if self.paged:
+                t = tok.shape[1]
+                logical = (start_arr[:, None].astype(INDEX_DTYPE)
+                           + np.arange(t, dtype=INDEX_DTYPE)[None, :])
+                valid = (np.arange(t, dtype=INDEX_DTYPE)[None, :]
+                         < as_index(lengths)[:, None])
+                psc = self._scatter_indices(slot_ids, logical, valid)
+                pgi = self._gather_indices(slot_ids)
+                logits, self.cache = self._resume_prefill_paged(
+                    self.params, self.cache, jnp.asarray(tok),
+                    jnp.asarray(lengths), jnp.asarray(start_arr),
+                    jnp.asarray(psc), jnp.asarray(pgi))
+            else:
+                logits, self.cache = self._resume_prefill(
+                    self.params, self.cache, jnp.asarray(tok),
+                    jnp.asarray(lengths), jnp.asarray(start_arr),
+                    jnp.asarray(slot_ids))
+        self._device_wait(logits)
         self.counters["resume_calls"] += 1
         return logits
 
@@ -772,30 +780,31 @@ class PhaseExecutor:
         dispatch, so under a tight ``capacity_factor`` the active requests'
         outputs can differ (deterministically) from a smaller-batch run —
         the same effect batch composition has in any capacity-dropped MoE."""
-        if self.paged and self.fused_decode != "off":
-            rows = np.arange(self.n_slots)
-            li = as_index(lengths)
-            psc = self._scatter_indices(rows, li, li > 0)
-            logits, vals, ids, lse, self.cache = self._decode_fused(
-                self.params, self.cache, jnp.asarray(tokens, np.int32),
-                jnp.asarray(lengths, np.int32), jnp.asarray(psc),
-                jnp.asarray(self._table_mat))
-            self._stash_fused_select(logits, vals, ids, lse)
-            self.counters["fused_decode_steps"] += 1
-        elif self.paged:
-            rows = np.arange(self.n_slots)
-            li = as_index(lengths)
-            psc = self._scatter_indices(rows, li, li > 0)
-            pgi = self._gather_indices(rows)
-            logits, self.cache = self._decode_paged(
-                self.params, self.cache, jnp.asarray(tokens, np.int32),
-                jnp.asarray(lengths, np.int32), jnp.asarray(psc),
-                jnp.asarray(pgi))
-        else:
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(tokens, np.int32),
-                jnp.asarray(lengths, np.int32))
-        logits.block_until_ready()
+        with span("serve.stage"):
+            if self.paged and self.fused_decode != "off":
+                rows = np.arange(self.n_slots)
+                li = as_index(lengths)
+                psc = self._scatter_indices(rows, li, li > 0)
+                logits, vals, ids, lse, self.cache = self._decode_fused(
+                    self.params, self.cache, jnp.asarray(tokens, np.int32),
+                    jnp.asarray(lengths, np.int32), jnp.asarray(psc),
+                    jnp.asarray(self._table_mat))
+                self._fused_select = (logits, vals, ids, lse)
+                self.counters["fused_decode_steps"] += 1
+            elif self.paged:
+                rows = np.arange(self.n_slots)
+                li = as_index(lengths)
+                psc = self._scatter_indices(rows, li, li > 0)
+                pgi = self._gather_indices(rows)
+                logits, self.cache = self._decode_paged(
+                    self.params, self.cache, jnp.asarray(tokens, np.int32),
+                    jnp.asarray(lengths, np.int32), jnp.asarray(psc),
+                    jnp.asarray(pgi))
+            else:
+                logits, self.cache = self._decode(
+                    self.params, self.cache, jnp.asarray(tokens, np.int32),
+                    jnp.asarray(lengths, np.int32))
+        self._device_wait(logits)
         self.counters["decode_steps"] += 1
         return logits
 
@@ -816,59 +825,62 @@ class PhaseExecutor:
         if C > self.n_candidates:
             raise ValueError(f"{C} branches exceed the executor's "
                              f"n_candidates capacity ({self.n_candidates})")
-        if self.paged:
-            # branch b of row i writes logical position
-            # starts[i] + b*stride + (lengths[i] - starts[i]); inactive
-            # rows and dummy branches resolve to the drop index here, on
-            # the host — the program itself is gating-free
-            rows = np.arange(self.n_slots)
-            li = as_index(lengths)[:, None]
-            st = as_index(starts)[:, None]
-            b = np.arange(C, dtype=INDEX_DTYPE)[None, :]
-            logical = st + b * self.branch_stride + (li - st)
-            valid = (li > 0) & (b < as_index(counts)[:, None])
-            psc = self._scatter_indices(rows, logical, valid)
-            if self.fused_decode != "off":
-                logits, vals, ids, lse, self.cache = self._decode_multi_fused(
-                    self.params, self.cache, jnp.asarray(tokens, np.int32),
-                    jnp.asarray(lengths, np.int32),
-                    jnp.asarray(starts, np.int32), jnp.asarray(psc),
-                    jnp.asarray(self._table_mat))
-                self._stash_fused_select(logits, vals, ids, lse)
-                self.counters["fused_decode_steps"] += 1
+        with span("serve.stage"):
+            if self.paged:
+                # branch b of row i writes logical position
+                # starts[i] + b*stride + (lengths[i] - starts[i]); inactive
+                # rows and dummy branches resolve to the drop index here,
+                # on the host — the program itself is gating-free
+                rows = np.arange(self.n_slots)
+                li = as_index(lengths)[:, None]
+                st = as_index(starts)[:, None]
+                b = np.arange(C, dtype=INDEX_DTYPE)[None, :]
+                logical = st + b * self.branch_stride + (li - st)
+                valid = (li > 0) & (b < as_index(counts)[:, None])
+                psc = self._scatter_indices(rows, logical, valid)
+                if self.fused_decode != "off":
+                    (logits, vals, ids, lse,
+                     self.cache) = self._decode_multi_fused(
+                        self.params, self.cache,
+                        jnp.asarray(tokens, np.int32),
+                        jnp.asarray(lengths, np.int32),
+                        jnp.asarray(starts, np.int32), jnp.asarray(psc),
+                        jnp.asarray(self._table_mat))
+                    self._fused_select = (logits, vals, ids, lse)
+                    self.counters["fused_decode_steps"] += 1
+                else:
+                    pgi = self._gather_indices(rows)
+                    logits, self.cache = self._decode_multi_paged(
+                        self.params, self.cache,
+                        jnp.asarray(tokens, np.int32),
+                        jnp.asarray(lengths, np.int32),
+                        jnp.asarray(starts, np.int32), jnp.asarray(psc),
+                        jnp.asarray(pgi))
             else:
-                pgi = self._gather_indices(rows)
-                logits, self.cache = self._decode_multi_paged(
+                logits, self.cache = self._decode_multi(
                     self.params, self.cache, jnp.asarray(tokens, np.int32),
                     jnp.asarray(lengths, np.int32),
-                    jnp.asarray(starts, np.int32), jnp.asarray(psc),
-                    jnp.asarray(pgi))
-        else:
-            logits, self.cache = self._decode_multi(
-                self.params, self.cache, jnp.asarray(tokens, np.int32),
-                jnp.asarray(lengths, np.int32),
-                jnp.asarray(starts, np.int32), jnp.asarray(counts, np.int32))
-        logits.block_until_ready()
+                    jnp.asarray(starts, np.int32),
+                    jnp.asarray(counts, np.int32))
+        self._device_wait(logits)
         self.counters["decode_steps"] += 1
         self.counters["decode_multi_steps"] += 1
         self.counters["branch_tokens"] += int(np.sum(counts))
         return logits
 
-    def _stash_fused_select(self, logits, vals, ids, lse) -> None:
-        """Hold the select results the fused decode program computed
-        alongside its logits, keyed by the logits array IDENTITY — the
-        scheduler's next ``select_scored(logits)`` call is then answered
-        from the stash (no second dispatch).  The stashed logits reference
-        keeps the key alive, so an ``id`` collision is impossible."""
-        self._fused_select = (logits, np.asarray(vals), np.asarray(ids),
-                              np.asarray(lse))
+    def _device_wait(self, logits: jax.Array) -> None:
+        """Block until a phase program's output is ready: the host time a
+        round spends waiting on the device, outside its host work."""
+        with span("serve.device_wait", self.counters):
+            logits.block_until_ready()
 
     def select(self, logits) -> Tuple[np.ndarray, np.ndarray]:
         """Top-k over logits; returns host (vals, ids)."""
         self.counters["select_calls"] += 1
-        vals, ids = self._select(logits)
-        # the scheduler's one sanctioned phase-boundary readback
-        return np.asarray(vals), np.asarray(ids)  # lint: allow[hidden-host-sync]
+        with span("serve.select", self.counters):
+            vals, ids = self._select(logits)
+            # the scheduler's one sanctioned phase-boundary readback
+            return np.asarray(vals), np.asarray(ids)  # lint: allow[hidden-host-sync]
 
     def select_scored(self, logits
                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -879,23 +891,23 @@ class PhaseExecutor:
         branch axis is flattened for the kernel and restored).
 
         When ``logits`` came out of a FUSED decode step the answer was
-        already computed inside that one program; it is served from the
-        stash and no select program dispatches."""
+        already computed inside that one program (``decode`` holds it,
+        keyed by the logits array IDENTITY); it is read back from there
+        and no select program dispatches."""
         shape = logits.shape
         if self._fused_select is not None and logits is self._fused_select[0]:
             _, vals, ids, lse = self._fused_select
             self._fused_select = None
             self.counters["fused_select_hits"] += 1
-            vals = vals.reshape(shape[:-1] + (self.topk,))
-            ids = ids.reshape(shape[:-1] + (self.topk,))
-            return vals, ids, lse.reshape(shape[:-1])
-        self.counters["select_calls"] += 1
-        if len(shape) > 2:
-            logits = logits.reshape((-1, shape[-1]))
-        vals, ids, lse = self._select_scored(logits)
-        # sanctioned phase-boundary readback (see select)
-        vals, ids = np.asarray(vals), np.asarray(ids)  # lint: allow[hidden-host-sync]
-        lse = np.asarray(lse)  # lint: allow[hidden-host-sync]
+        else:
+            self.counters["select_calls"] += 1
+            if len(shape) > 2:
+                logits = logits.reshape((-1, shape[-1]))
+            vals, ids, lse = self._select_scored(logits)
+        with span("serve.select", self.counters):
+            # sanctioned phase-boundary readback (see select)
+            vals, ids = np.asarray(vals), np.asarray(ids)  # lint: allow[hidden-host-sync]
+            lse = np.asarray(lse)  # lint: allow[hidden-host-sync]
         if len(shape) > 2:
             vals = vals.reshape(shape[:-1] + (self.topk,))
             ids = ids.reshape(shape[:-1] + (self.topk,))

@@ -77,10 +77,15 @@ slowest member finishes.  Both schedulers drive the same compiled programs,
 so an A/B between them isolates pure scheduling effects.
 
 Latency accounting is per REQUEST (arrival -> last token realized on host),
-not per batch; occupancy is sampled at every decode step.  Join-step wall
-times (the prefill work one engine step performs) are sampled per round so
-the engine can report join p99 and the decode-stall fraction — the metrics
-the chunked-prefill claim is measured by.
+not per batch, and splits exactly into queue wait (arrival -> first
+admission into a prefill group), prefill phase (-> first token on the
+host) and decode phase (-> last token): both schedulers stamp each request
+at admission and at every realized token, and ``Completion`` carries the
+stamps.  Occupancy is sampled at every decode step.  Join-step wall times
+(the prefill work one engine step performs) are the ``serve.advance`` +
+``serve.join`` spans of each round that ran a prefill program, so the
+engine can report join p99 and the decode-stall fraction — the metrics the
+chunked-prefill claim is measured by.
 """
 
 from __future__ import annotations
@@ -95,6 +100,7 @@ import numpy as np
 from repro.serving.executor import PhaseExecutor, bucket_length
 from repro.serving.kv_cache import (PrefixEntry, PrefixStore, SlotPool,
                                     SlotState, prefix_hash_chain)
+from repro.serving.spans import rids, span
 
 _NO_DEADLINE = float("inf")
 
@@ -133,6 +139,10 @@ class Request:
     # memoized prefix-digest chain (content is immutable, the scheduler
     # re-plans every round — hash once, not once per round)
     chain: Optional[List[Tuple[int, str]]] = None
+    # lifecycle stamps (perf_counter): first admission into a prefill
+    # group, and each token of the current decode realized on the host
+    admitted_s: Optional[float] = None
+    token_s: List[float] = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -149,6 +159,45 @@ class Completion:
     # unscored.
     items: List[np.ndarray] = dataclasses.field(default_factory=list)
     scores: List[float] = dataclasses.field(default_factory=list)
+    # lifecycle stamps (perf_counter): arrival, first admission into a
+    # prefill group, and each token realized on the host (the last one is
+    # the finish, so latency_s = queue wait + prefill phase + decode phase)
+    arrival_s: float = 0.0
+    admitted_s: float = 0.0
+    token_s: List[float] = dataclasses.field(default_factory=list)
+
+    @property
+    def queue_wait_s(self) -> float:
+        return self.admitted_s - self.arrival_s
+
+    @property
+    def prefill_phase_s(self) -> float:
+        return self.token_s[0] - self.admitted_s
+
+    @property
+    def decode_phase_s(self) -> float:
+        return self.token_s[-1] - self.token_s[0]
+
+
+def _completion(r: Request, item_list: List[np.ndarray],
+                scores: List[float]) -> Completion:
+    """The Completion of ``r`` once its last token is on the host."""
+    finish = r.token_s[-1]
+    return Completion(
+        rid=r.rid, item=item_list[0], items=item_list, scores=scores,
+        latency_s=finish - r.arrival_s, priority=r.priority,
+        deadline_s=r.deadline_s,
+        deadline_missed=r.deadline_s is not None and finish > r.deadline_s,
+        arrival_s=r.arrival_s, admitted_s=r.admitted_s,
+        token_s=list(r.token_s))
+
+
+def _admit(r: Request, t: float, queue_wait_s: List[float]) -> None:
+    """Stamp ``r``'s first admission into a prefill group (a preempted
+    request keeps its first one) and sample its queue wait."""
+    if r.admitted_s is None:
+        r.admitted_s = t
+        queue_wait_s.append(t - r.arrival_s)
 
 
 @dataclasses.dataclass
@@ -274,6 +323,7 @@ class ContinuousScheduler:
         # -- join-step / SLA accounting (read by the engine) --
         self.join_step_s: List[float] = []   # wall time of each prefill round
         self.decode_stall_s = 0.0   # join time spent while decoders waited
+        self.queue_wait_s: List[float] = []  # per request first admitted
         self.preemptions = 0
         self.holds = 0            # join rounds deferred by the hold window
 
@@ -349,6 +399,7 @@ class ContinuousScheduler:
         self.occupancy = []
         self.join_step_s = []
         self.decode_stall_s = 0.0
+        self.queue_wait_s = []
         self.preemptions = 0
         self.holds = 0
 
@@ -359,14 +410,15 @@ class ContinuousScheduler:
         return [s for s in self.pool.used_slots() if s not in self._pending]
 
     def _seed_slot(self, slot: int, r: Request, ids_row: np.ndarray,
-                   vals_row: np.ndarray, lse: float, done: List[Completion],
-                   freed: List[int]) -> None:
+                   vals_row: np.ndarray, lse: float, t: float,
+                   done: List[Completion], freed: List[int]) -> None:
         """Fork a freshly prefilled slot into its candidate branches: the
         top-``n_candidates`` tokens of the prefill logits seed one branch
         each, scored by their log-prob.  A forced ``first_token`` (the
         sequential differential reference) seeds the single branch with
         that token instead (its score is looked up among the top-k when
-        present, else 0 — forcing is a harness hook, not a ranked path)."""
+        present, else 0 — forcing is a harness hook, not a ranked path).
+        ``t`` is when the prefill's selection reached the host."""
         state = self.pool[slot]
         if r.first_token is not None:
             seeds = [int(r.first_token)]
@@ -379,6 +431,7 @@ class ContinuousScheduler:
         state.branch_base = state.length
         state.branches = [[s] for s in seeds]
         state.scores = lps
+        r.token_s = [t]
         self._maybe_retire(slot, done, freed)     # decode_len == 1 corner
 
     def _maybe_retire(self, slot: int, done: List[Completion],
@@ -391,24 +444,15 @@ class ContinuousScheduler:
             return
         final = self.pool.free(slot)
         freed.append(slot)
-        self._slot_request.pop(slot, None)
+        r = self._slot_request.pop(slot)
         entry = self._slot_entry.pop(slot, None)
         if entry is not None:           # unpin the prefix backing this slot
             self.store.release(entry)
-        finish = time.perf_counter()
         order = sorted(range(final.n_candidates),
                        key=lambda b: (-final.scores[b], b))
-        items = [np.asarray(final.branches[b], np.int32) for b in order]
-        done.append(Completion(
-            rid=final.request_id,
-            item=items[0],
-            items=items,
-            scores=[final.scores[b] for b in order],
-            latency_s=finish - final.arrival_s,
-            priority=final.priority,
-            deadline_s=final.deadline_s,
-            deadline_missed=final.deadline_s is not None
-            and finish > final.deadline_s))
+        done.append(_completion(
+            r, [np.asarray(final.branches[b], np.int32) for b in order],
+            [final.scores[b] for b in order]))
 
     def _plan(self, r: Request) -> Optional[Tuple[PrefixEntry, int]]:
         """Longest usable cached prefix for ``r`` as ``(entry, n_tokens)``
@@ -513,6 +557,7 @@ class ContinuousScheduler:
         so its latency accounting spans the preemption.
         """
         r = self._slot_request.pop(slot)
+        r.token_s = []                    # its tokens are decoded again
         self.pool.free(slot)
         if self.store is not None:
             n_full = (len(r.tokens) // self.store.n_codebooks) \
@@ -602,7 +647,10 @@ class ContinuousScheduler:
             slots = by_bucket[b]
             segments = [self._pending[s].left[:chunk] for s in slots]
             starts = [self._pending[s].next_start for s in slots]
-            logits = self.executor.resume_prefill(segments, slots, starts)
+            with span("serve.resume", rids=rids(
+                    self._pending[s].request for s in slots)):
+                logits = self.executor.resume_prefill(segments, slots,
+                                                      starts)
             finished: List[Tuple[int, int, Request]] = []  # (row, slot, r)
             for i, slot in enumerate(slots):
                 p = self._pending[slot]
@@ -611,15 +659,20 @@ class ContinuousScheduler:
                 if len(p.left) == 0:
                     del self._pending[slot]
                     if self.store is not None:
-                        self._offer_to_store([p.request], [slot], [p.plan])
+                        with span("serve.store"):
+                            self._offer_to_store([p.request], [slot],
+                                                 [p.plan])
                     finished.append((i, slot, p.request))
             if finished:
                 vals, ids, lse = self.executor.select_scored(logits)
+                t = time.perf_counter()
                 freed: List[int] = []
-                for i, slot, r in finished:
-                    self._seed_slot(slot, r, ids[i], vals[i],
-                                    float(lse[i]), done, freed)
-                self.executor.free_slots(freed)
+                with span("serve.retire"):
+                    for i, slot, r in finished:
+                        self._seed_slot(slot, r, ids[i], vals[i],
+                                        float(lse[i]), t, done, freed)
+                with span("serve.free"):
+                    self.executor.free_slots(freed)
 
     # -- admission ------------------------------------------------------------
 
@@ -704,6 +757,9 @@ class ContinuousScheduler:
                 if id(r) not in taken:
                     queue.append(r)
         for (is_hit, _), group in groups.items():
+            t_admit = time.perf_counter()
+            for r in group:
+                _admit(r, t_admit, self.queue_wait_s)
             group_plans = [plans[id(r)] for r in group]
             slots = []
             for r in group:
@@ -738,9 +794,10 @@ class ContinuousScheduler:
                             for r, (_, n_tok) in zip(group, group_plans)]
                 first_lens = [self.policy.first_segment(len(s))
                               for s in suffixes]
-                logits = self.executor.resume_prefill(
-                    [s[:n] for s, n in zip(suffixes, first_lens)],
-                    slots, starts)
+                with span("serve.resume", rids=rids(group)):
+                    logits = self.executor.resume_prefill(
+                        [s[:n] for s, n in zip(suffixes, first_lens)],
+                        slots, starts)
             else:
                 if self.paged:
                     for slot, r in zip(slots, group):
@@ -750,9 +807,10 @@ class ContinuousScheduler:
                 starts = [1] * len(group)          # after the profile token
                 first_lens = [self.policy.first_segment(len(r.tokens))
                               for r in group]
-                logits = self.executor.prefill_insert(
-                    [r.tokens[:n] for r, n in zip(group, first_lens)],
-                    [r.profile for r in group], slots)
+                with span("serve.prefill", rids=rids(group)):
+                    logits = self.executor.prefill_insert(
+                        [r.tokens[:n] for r, n in zip(group, first_lens)],
+                        [r.profile for r in group], slots)
             self._register_segments(group, slots, group_plans, first_lens,
                                     starts)
             # offer COMPLETE rows to the store before any retire can clear
@@ -761,19 +819,23 @@ class ContinuousScheduler:
                                                      group_plans)
                         if s not in self._pending]
             if self.store is not None and complete:
-                self._offer_to_store([c[0] for c in complete],
-                                     [c[1] for c in complete],
-                                     [c[2] for c in complete])
+                with span("serve.store"):
+                    self._offer_to_store([c[0] for c in complete],
+                                         [c[1] for c in complete],
+                                         [c[2] for c in complete])
             vals, ids, lse = self.executor.select_scored(logits)
+            t = time.perf_counter()
             freed: List[int] = []
-            for i, slot in enumerate(slots):
-                if slot in self._pending:
-                    continue        # mid-chunk: logits are not next-token
-                self._seed_slot(slot, group[i], ids[i], vals[i],
-                                float(lse[i]), done, freed)
+            with span("serve.retire"):
+                for i, slot in enumerate(slots):
+                    if slot in self._pending:
+                        continue    # mid-chunk: logits are not next-token
+                    self._seed_slot(slot, group[i], ids[i], vals[i],
+                                    float(lse[i]), t, done, freed)
             # clear before the NEXT group can reallocate a freed slot
             # (reachable only when decode_len == 1: prefill completes)
-            self.executor.free_slots(freed)
+            with span("serve.free"):
+                self.executor.free_slots(freed)
 
     def _decode_step(self, done: List[Completion]) -> None:
         """One length-masked decode over the decoding slots of the pool.
@@ -793,6 +855,7 @@ class ContinuousScheduler:
         width = max((pool[s].n_candidates for s in active), default=1)
         n_branches = sum(pool[s].n_candidates for s in active)
         self.occupancy.append(pool.occupancy)
+        decoding = rids(self._slot_request[s] for s in active)
         freed: List[int] = []
         if width == 1:
             tokens = np.zeros((pool.n_slots, 1), np.int32)
@@ -800,15 +863,19 @@ class ContinuousScheduler:
             for s in active:
                 tokens[s, 0] = pool[s].branches[0][-1]
                 lengths[s] = pool[s].length
-            logits = self.executor.decode(tokens, lengths)
+            with span("serve.decode", rids=decoding):
+                logits = self.executor.decode(tokens, lengths)
             self.executor.counters["branch_tokens"] += n_branches
             vals, ids, lse = self.executor.select_scored(logits)
-            for s in active:
-                st = pool[s]
-                st.length += 1           # the input token we just wrote
-                st.branches[0].append(int(ids[s, 0]))
-                st.scores[0] += float(vals[s, 0] - lse[s])
-                self._maybe_retire(s, done, freed)
+            t = time.perf_counter()
+            with span("serve.retire"):
+                for s in active:
+                    st = pool[s]
+                    st.length += 1           # the input token we just wrote
+                    st.branches[0].append(int(ids[s, 0]))
+                    st.scores[0] += float(vals[s, 0] - lse[s])
+                    self._slot_request[s].token_s.append(t)
+                    self._maybe_retire(s, done, freed)
         else:
             # branch width buckets to a power of two (capped at the
             # executor's capacity) so mixed-K traffic compiles a handful
@@ -826,17 +893,22 @@ class ContinuousScheduler:
                 lengths[s] = st.length
                 starts[s] = st.branch_base
                 counts[s] = st.n_candidates
-            logits = self.executor.decode_multi(tokens, lengths, starts,
-                                                counts)
+            with span("serve.decode", rids=decoding):
+                logits = self.executor.decode_multi(tokens, lengths, starts,
+                                                    counts)
             vals, ids, lse = self.executor.select_scored(logits)
-            for s in active:
-                st = pool[s]
-                st.length += 1
-                for b in range(st.n_candidates):
-                    st.branches[b].append(int(ids[s, b, 0]))
-                    st.scores[b] += float(vals[s, b, 0] - lse[s, b])
-                self._maybe_retire(s, done, freed)
-        self.executor.free_slots(freed)  # one clear program per step
+            t = time.perf_counter()
+            with span("serve.retire"):
+                for s in active:
+                    st = pool[s]
+                    st.length += 1
+                    for b in range(st.n_candidates):
+                        st.branches[b].append(int(ids[s, b, 0]))
+                        st.scores[b] += float(vals[s, b, 0] - lse[s, b])
+                    self._slot_request[s].token_s.append(t)
+                    self._maybe_retire(s, done, freed)
+        with span("serve.free"):
+            self.executor.free_slots(freed)  # one clear program per step
 
     # -- the step state machine ----------------------------------------------
 
@@ -847,20 +919,22 @@ class ContinuousScheduler:
         loops sleep on ``idle_wait_s()`` instead of spinning."""
         done: List[Completion] = []
         # join-step accounting: everything before decode is prefill work;
-        # time it only when a prefill program actually ran, and charge it
+        # count it only when a prefill program actually ran, and charge it
         # to decode stall when decoders sat waiting on it
         had_decoders = bool(self._decoding_slots())
-        t0 = time.perf_counter()
         n0 = self.executor.counters["prefill_calls"]
-        self._advance_prefills(done)
-        self._join(self.queue, done)
+        with span("serve.advance") as advance:
+            self._advance_prefills(done)
+        with span("serve.join") as join:
+            self._join(self.queue, done)
         if self.executor.counters["prefill_calls"] > n0:
-            dt = time.perf_counter() - t0
+            dt = advance.s + join.s
             self.join_step_s.append(dt)
             if had_decoders:
                 self.decode_stall_s += dt
         if self._decoding_slots():
-            self._decode_step(done)
+            with span("serve.decode_round"):
+                self._decode_step(done)
         return done
 
     def run(self, requests: List[Request]) -> List[Completion]:
@@ -917,6 +991,7 @@ class FixedBatchScheduler:
         self.occupancy: List[float] = []
         self.join_step_s: List[float] = []
         self.decode_stall_s = 0.0    # lock-step: decode never overlaps join
+        self.queue_wait_s: List[float] = []  # per request first admitted
         self.preemptions = 0
         self.holds = 0               # fixed mode has no admission holds
 
@@ -962,6 +1037,7 @@ class FixedBatchScheduler:
         self.occupancy = []
         self.join_step_s = []
         self.decode_stall_s = 0.0
+        self.queue_wait_s = []
         self.preemptions = 0
         self.holds = 0
 
@@ -985,6 +1061,9 @@ class FixedBatchScheduler:
             return False
         for _ in range(need):
             self.queue.popleft()
+        t_admit = time.perf_counter()
+        for r in chunk:
+            _admit(r, t_admit, self.queue_wait_s)
         B = self.batch_size
         padded = chunk + [chunk[-1]] * (B - need)  # tail padding
         slots = []
@@ -993,11 +1072,14 @@ class FixedBatchScheduler:
                 request_id=r.rid, length=len(r.tokens) + 1,
                 arrival_s=r.arrival_s, priority=r.priority,
                 deadline_s=r.deadline_s)))
-        t0 = time.perf_counter()
-        logits = self.executor.prefill_insert(
-            [r.tokens for r in padded], [r.profile for r in padded], slots)
+        with span("serve.prefill", rids=rids(chunk)):
+            logits = self.executor.prefill_insert(
+                [r.tokens for r in padded], [r.profile for r in padded],
+                slots)
         _, ids = self.executor.select(logits)
-        self.join_step_s.append(time.perf_counter() - t0)
+        t = time.perf_counter()
+        for r in chunk:
+            r.token_s = [t]
         ids = ids[:len(slots)]                  # drop bucket-pad rows
         self._active = _FixedBatch(
             requests=chunk, slots=slots,
@@ -1014,40 +1096,43 @@ class FixedBatchScheduler:
         lens = np.zeros((self.pool.n_slots,), np.int32)
         tokens[b.slots, 0] = b.last[:, 0]
         lens[b.slots] = b.lengths
-        logits = self.executor.decode(tokens, lens)
+        with span("serve.decode", rids=rids(b.requests)):
+            logits = self.executor.decode(tokens, lens)
         _, ids = self.executor.select(logits)
+        t = time.perf_counter()
         self.occupancy.append(len(b.requests) / self.pool.n_slots)
         b.lengths = b.lengths + 1
         b.last = np.asarray(ids[b.slots, :1], np.int32)
         for row, toks in enumerate(b.gen):
             toks.append(int(b.last[row, 0]))
+        for r in b.requests:
+            r.token_s.append(t)
         b.steps_left -= 1
 
     def _retire(self) -> List[Completion]:
         b, self._active = self._active, None
-        finish = time.perf_counter()
-        done = []
-        for row, r in enumerate(b.requests):  # drop padded duplicates
-            item = np.asarray(b.gen[row], np.int32)
-            done.append(Completion(
-                rid=r.rid, item=item, items=[item],
-                latency_s=finish - r.arrival_s,
-                priority=r.priority, deadline_s=r.deadline_s,
-                deadline_missed=r.deadline_s is not None
-                and finish > r.deadline_s))
-        retired = sorted(set(b.slots))
-        for s in retired:
-            self.pool.free(s)
-        self.executor.free_slots(retired)   # one clear per batch
+        with span("serve.retire"):
+            done = [_completion(r, [np.asarray(b.gen[row], np.int32)], [])
+                    for row, r in enumerate(b.requests)]  # no padded rows
+            retired = sorted(set(b.slots))
+            for s in retired:
+                self.pool.free(s)
+        with span("serve.free"):
+            self.executor.free_slots(retired)   # one clear per batch
         return done
 
     def step(self) -> List[Completion]:
         """One lock-step round: form-and-prefill the next batch, or decode
         the active one; the batch retires when its last decode lands."""
-        if self._active is None and not self._form_batch():
-            return []
+        if self._active is None:
+            with span("serve.join") as join:
+                formed = self._form_batch()
+            if not formed:
+                return []
+            self.join_step_s.append(join.s)
         if self._active.steps_left > 0:
-            self._decode_once()
+            with span("serve.decode_round"):
+                self._decode_once()
         if self._active.steps_left == 0:
             return self._retire()
         return []
