@@ -345,6 +345,80 @@ def fp8_linear(
     return out.astype(out_dtype)
 
 
+# ---------------------------------------------------------------------------
+# Block-scaled fp8 GEMM (XLA path; the Pallas kernels fuse the same math)
+# ---------------------------------------------------------------------------
+
+# Trace-time tally of the form each lowered block-scaled GEMM took; module
+# state like ``_ACT_AMAX``.  A jitted program adds its GEMMs once, when it
+# lowers, so the counts say which programs run which form.
+_GEMM_FORMS: Dict[str, int] = {"scaled_out": 0, "dequant": 0}
+
+
+def gemm_form_counts() -> Dict[str, int]:
+    """``{"scaled_out": n, "dequant": m}``: block-scaled GEMMs lowered in
+    each form so far in this process (``_block_gemm``)."""
+    return dict(_GEMM_FORMS)
+
+
+def _scales_on_partials(rows: int, block: int) -> bool:
+    """Pick the form of ``_block_gemm`` that moves fewer bytes.
+
+    Scaling the partials keeps one f32 partial per K-block,
+    ``E * (K/b) * rows * N * 4`` B; dequantizing writes one bf16 copy of the
+    weight, ``E * K * N * 2`` B.  The partials are the smaller while
+    ``4 * rows < 2 * b``, i.e. ``rows < b / 2``.
+    """
+    return 2 * rows < block
+
+
+def _block_gemm(x: jax.Array, data: jax.Array, scale: jax.Array, block: int,
+                fmt) -> jax.Array:
+    """x (E, C, K) @ block-quantized (data (E, K, N), scale (E, K/b, N/b)).
+
+    The paper's ``1 x b`` activation / ``b x b`` weight scheme with f32
+    accumulation; the fp8 values and the scales are the same in both forms,
+    which differ only in where the scales are applied (chosen from C by
+    ``_scales_on_partials``).  Neither materializes a scale of the weight's
+    size.  Returns f32 (E, C, N).
+    """
+    b = block
+    E, C, K = x.shape
+    N = data.shape[-1]
+    kb = K // b
+    xq = quantize_blockwise(x, block=b, fmt=fmt, act=True)       # scale (E, C, kb)
+    form = "scaled_out" if _scales_on_partials(C, b) else "dequant"
+    _GEMM_FORMS[form] += 1
+    with jax.named_scope(f"moe_gemm.{form}"):
+        if form == "scaled_out":
+            # out = sum_kb (Xq_kb . Wq_kb) * s_x[c, kb] * s_w[kb, n]: the fp8
+            # payloads are exact in bf16, one batched dot over (E, kb) merged
+            # (XLA:CPU refuses a bf16 dot with two batch dims), and each
+            # weight scale repeated along its own b columns only.
+            xd = (xq.data.reshape(E, C, kb, b).transpose(0, 2, 1, 3)
+                  .reshape(E * kb, C, b).astype(jnp.bfloat16))
+            wd = data.reshape(E * kb, b, N).astype(jnp.bfloat16)
+            part = jnp.einsum("gcb,gbn->gcn", xd, wd,
+                              preferred_element_type=jnp.float32)
+            part = (part.reshape(E, kb, C, N)
+                    * jnp.swapaxes(xq.scale, 1, 2)[..., None]
+                    * jnp.repeat(scale, b, axis=-1)[:, :, None, :])
+            return jnp.sum(part, axis=1)
+        # Fold each block scale into its fp8-grid operand, then ONE dot: on
+        # v5e (no fp8 MXU path) fp8 is the storage and bandwidth format.
+        # The barrier keeps the weight's dequant one elementwise pass that
+        # writes the bf16 copy: fused into the dot instead, XLA:TPU
+        # materializes the scale broadcast to the weight's size in f32.
+        xd = (xq.data.reshape(E, C, kb, b).astype(jnp.float32)
+              * xq.scale[..., None]).astype(jnp.bfloat16).reshape(E, C, K)
+        wd = jax.lax.optimization_barrier(data.reshape(E, kb, b, N))
+        wd = (wd.astype(jnp.float32)
+              * jnp.repeat(scale, b, axis=-1)[:, :, None, :]
+              ).astype(jnp.bfloat16).reshape(E, K, N)
+        return jnp.einsum("eck,ekn->ecn", xd, wd,
+                          preferred_element_type=jnp.float32)
+
+
 def fp8_block_matmul(
     x: jax.Array,
     wq: QuantizedTensor,
@@ -352,33 +426,16 @@ def fp8_block_matmul(
     fmt=E4M3,
     out_dtype=None,
 ) -> jax.Array:
-    """Block-scaled matmul for MoE grouped GEMM (paper: 1x128 act, 128x128 w).
-
-    Block scales cannot fold outside a single dot, so the XLA path quantizes
-    both operands onto the fp8 grid and contracts per K-block with f32
-    accumulation, applying ``s_x[token, kb] * s_w[kb, nb]`` on each partial.
-    The Pallas kernel (``repro.kernels.fp8_gemm``) performs the identical
-    math with the accumulator resident in VMEM.
-    """
+    """Block-scaled matmul (paper: 1x128 act, 128x128 w): x (..., K) @ wq
+    (K, N), as one group of ``_block_gemm`` over the flattened rows.  The
+    Pallas kernel (``repro.kernels.fp8_gemm``) performs the identical math
+    with the accumulator resident in VMEM."""
     out_dtype = out_dtype or x.dtype
     if wq.granularity != "block":
         raise ValueError("fp8_block_matmul needs block-quantized weights")
-    b = wq.block
-    xq = quantize_blockwise(x, block=b, fmt=fmt, act=True)
-    K = x.shape[-1]
-    N = wq.data.shape[-1]
-    kb = K // b
-    # Fold each block scale into its (fp8-grid) operand, then ONE dot with
-    # f32 accumulation:  sum_k (x_qk * s_xk) . (w_qk * s_wk).  Mathematically
-    # identical to scaling the per-block partial products; on TPU v5e (no
-    # native fp8 MXU path) this bf16-scaled form IS the production lowering —
-    # fp8 serves as the storage/bandwidth format (DESIGN.md §3).
-    xd = (xq.data.reshape(*x.shape[:-1], kb, b).astype(jnp.float32)
-          * xq.scale[..., None]).astype(jnp.bfloat16).reshape(x.shape)
-    sw = jnp.repeat(jnp.repeat(wq.scale, b, axis=-2), b, axis=-1)
-    wd = (wq.data.astype(jnp.float32) * sw).astype(jnp.bfloat16)
-    out = jnp.dot(xd, wd, preferred_element_type=jnp.float32)
-    return out.astype(out_dtype)
+    out = _block_gemm(x.reshape(1, -1, x.shape[-1]), wq.data[None],
+                      wq.scale[None], wq.block, fmt)
+    return out.reshape(*x.shape[:-1], -1).astype(out_dtype)
 
 
 def fp8_grouped_matmul(
@@ -392,18 +449,7 @@ def fp8_grouped_matmul(
     out_dtype = out_dtype or x.dtype
     if wq.granularity != "block":
         raise ValueError("fp8_grouped_matmul needs block-quantized weights")
-    b = wq.block
-    E, C, K = x.shape
-    N = wq.data.shape[-1]
-    kb = K // b
-    xq = quantize_blockwise(x, block=b, fmt=fmt, act=True)       # scale (E, C, kb)
-    xd = (xq.data.reshape(E, C, kb, b).astype(jnp.float32)
-          * xq.scale[..., None]).astype(jnp.bfloat16).reshape(E, C, K)
-    sw = jnp.repeat(jnp.repeat(wq.scale, b, axis=-2), b, axis=-1)  # (E, K, N)
-    wd = (wq.data.astype(jnp.float32) * sw).astype(jnp.bfloat16)
-    out = jnp.einsum("eck,ekn->ecn", xd, wd,
-                     preferred_element_type=jnp.float32)
-    return out.astype(out_dtype)
+    return _block_gemm(x, wq.data, wq.scale, wq.block, fmt).astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------

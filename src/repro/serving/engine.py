@@ -45,6 +45,7 @@ import numpy as np
 from repro.configs.base import OneRecConfig
 from repro.core.policy import (BASELINE_POLICY, PAPER_POLICY, QuantPolicy,
                                load_policy_artifact)
+from repro.core.quant import gemm_form_counts
 from repro.serving.executor import PhaseExecutor
 from repro.serving.kv_cache import PrefixStore, SlotPool
 from repro.serving.requests import requests_from_arrays
@@ -275,6 +276,9 @@ class ServingEngine:
                 -(-(self.n_slots + prefix_rows) * s_row
                   // engine_cfg.page_size)
         quant_policy, act_scales = resolve_quant_policy(engine_cfg)
+        # block-scaled GEMM forms are tallied as programs lower; this
+        # engine's programs lower after this point
+        self._gemm_forms0 = gemm_form_counts()
         self.executor = PhaseExecutor(
             params, cfg, n_slots=self.n_slots, use_fp8=engine_cfg.use_fp8,
             topk=engine_cfg.topk, use_radix_topk=engine_cfg.use_radix_topk,
@@ -573,6 +577,12 @@ class ServingEngine:
             "host_s_per_step": float(np.mean(self._host_s))
             if self._host_s else 0.0,
             "preemptions": float(sched.preemptions),
+            # block-scaled fp8 GEMMs lowered by the programs built since
+            # the engine was (core/quant's form tally): decode's few rows
+            # per expert scale the partials, prefill's dequantize once
+            "moe_gemm_forms": {
+                k: n - self._gemm_forms0[k]
+                for k, n in gemm_form_counts().items()},
             **self._sla_stats(done),
             **self._prefix_stats(),
             **self._paged_stats(),

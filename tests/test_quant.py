@@ -1,6 +1,7 @@
 """Unit + property tests for the FP8 quantization primitives (paper §4.1)."""
 
 import jax
+import jax.extend.core as jax_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -8,6 +9,7 @@ import pytest
 from _hypothesis_compat import hnp, hypothesis, st
 
 from repro.core import quant
+from repro.kernels.fp8_grouped_gemm.ref import fp8_grouped_gemm_ref
 
 hypothesis.settings.register_profile(
     "ci", deadline=None, max_examples=30,
@@ -129,6 +131,66 @@ def test_grouped_matmul_paths_agree():
         rel = np.linalg.norm(np.asarray(out, np.float32) - ref) \
             / np.linalg.norm(ref)
         assert rel < 0.06, (q.granularity, rel)
+
+
+@pytest.mark.parametrize("C,form", [(8, "scaled_out"), (256, "dequant")])
+def test_grouped_matmul_forms(C, form):
+    """Few rows per expert scale the f32 partials (the kernel oracle's
+    order); many rows dequantize the weight once (bit for bit the plain
+    repeat-the-scales formula).  Both stay within fp8 error of an f32
+    einsum."""
+    E, K, N, b = 2, 256, 256, 128
+    kb = K // b
+    x = jax.random.normal(jax.random.PRNGKey(0), (E, C, K), jnp.bfloat16)
+    w = jax.random.normal(jax.random.PRNGKey(1), (E, K, N))
+    q = quant.quantize_blockwise(w)
+    before = quant.gemm_form_counts()
+    out = np.asarray(quant.fp8_grouped_matmul(x, q), np.float32)
+    after = quant.gemm_form_counts()
+    assert {k: after[k] - before[k] for k in after} == {
+        "scaled_out": int(form == "scaled_out"),
+        "dequant": int(form == "dequant")}
+    if form == "scaled_out":
+        oracle = np.asarray(fp8_grouped_gemm_ref(x, q.data, q.scale),
+                            np.float32)
+        # f32 summation order alone differs: one bf16 rounding apart
+        np.testing.assert_allclose(out, oracle, rtol=2.0 ** -7, atol=1e-6)
+    else:
+        xq = quant.quantize_blockwise(x, act=True)
+        xd = (xq.data.reshape(E, C, kb, b).astype(jnp.float32)
+              * xq.scale[..., None]).astype(jnp.bfloat16).reshape(E, C, K)
+        sw = jnp.repeat(jnp.repeat(q.scale, b, axis=-2), b, axis=-1)
+        wd = (q.data.astype(jnp.float32) * sw).astype(jnp.bfloat16)
+        oracle = np.asarray(jnp.einsum(
+            "eck,ekn->ecn", xd, wd, preferred_element_type=jnp.float32
+        ).astype(jnp.bfloat16), np.float32)
+        np.testing.assert_array_equal(out, oracle)
+    ref = np.einsum("eck,ekn->ecn", np.asarray(x, np.float32), np.asarray(w))
+    assert np.linalg.norm(out - ref) / np.linalg.norm(ref) < 0.06
+
+
+def _eqn_outputs(jaxpr):
+    """Every equation's output avals, sub-jaxprs (pjit, scan, ...) included."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                if isinstance(sub, jax_core.ClosedJaxpr):
+                    yield from _eqn_outputs(sub.jaxpr)
+                elif isinstance(sub, jax_core.Jaxpr):
+                    yield from _eqn_outputs(sub)
+
+
+def test_decode_grouped_matmul_has_no_weight_sized_f32():
+    """At a decode shape no step of the block-scaled GEMM writes an f32
+    array as large as the weight: no scale is broadcast to (E, K, N)."""
+    E, C, K, N = 2, 8, 256, 256
+    x = jax.ShapeDtypeStruct((E, C, K), jnp.bfloat16)
+    q = quant.quantize_blockwise(jnp.ones((E, K, N)))
+    closed = jax.make_jaxpr(lambda x, q: quant.fp8_grouped_matmul(x, q))(x, q)
+    big = [a for a in _eqn_outputs(closed.jaxpr)
+           if a.dtype == jnp.float32 and a.size >= E * K * N]
+    assert big == []
 
 
 def test_quantized_tensor_scans_and_jits():

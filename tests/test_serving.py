@@ -4,10 +4,12 @@ import jax
 import numpy as np
 import pytest
 
+from repro.configs.base import OneRecConfig, TransformerConfig
 from repro.configs.registry import get_arch
 from repro.data.onerec_data import OneRecStreamConfig, SemanticIDStream
 from repro.models import onerec as onerec_model
 from repro.serving import EngineConfig, ServingEngine
+from repro.serving.requests import make_request
 
 
 @pytest.fixture(scope="module")
@@ -61,3 +63,28 @@ def test_engine_deterministic(engine_setup):
     a, _ = eng.serve_requests(reqs)
     b, _ = eng.serve_requests(reqs)
     np.testing.assert_array_equal(np.stack(a), np.stack(b))
+
+
+def test_engine_reports_moe_gemm_forms():
+    """At 128-aligned widths the experts are block-quantized: decode's few
+    rows per expert take the scaled-partials form, prefill's many rows
+    the dequantize-once form, and ``stats()`` counts both."""
+    cfg = OneRecConfig(
+        name="onerec-gemm-forms-test",
+        history_len=8,
+        transformer=TransformerConfig(
+            name="onerec-gemm-forms-test-backbone",
+            n_layers=1, d_model=128, n_heads=4, n_kv_heads=2, head_dim=32,
+            d_ff=256, vocab_size=256, moe=True, n_experts=4, top_k=2,
+            d_expert=128, capacity_factor=16.0, ep_degree=4,
+            max_seq_len=64, remat=False),
+        serve_batch=2, beam_width=4)
+    params = onerec_model.init_onerec(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(0)
+    req = make_request(rng.integers(0, 192, size=8 * cfg.n_codebooks),
+                       rng.normal(size=onerec_model.PROFILE_DIM))
+    eng = ServingEngine(params, cfg, EngineConfig(batch_size=2))
+    _, stats = eng.serve_requests([req])
+    assert stats["prefill_calls"] >= 1 and stats["decode_steps"] >= 1
+    forms = stats["moe_gemm_forms"]
+    assert forms["scaled_out"] > 0 and forms["dequant"] > 0, forms

@@ -10,7 +10,9 @@ module is imported — because only one process at a time may load the TPU
 library.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,9 +22,11 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import registry
 from repro.core.policy import PAPER_POLICY
 from repro.core.ptq import quantized_leaf_programs
-from repro.core.quant import quantize_per_channel
+from repro.core.quant import (QuantizedTensor, fp8_grouped_matmul,
+                              quantize_per_channel)
 from repro.kernels.fp8_gemm.kernel import fp8_gemm_pallas
 from repro.kernels.paged_decode.kernel import paged_decode_pallas
+from repro.layers.moe import make_moe_spec
 from repro.models import onerec as onerec_model
 
 CFG = registry.get_arch("onerec-v2").CONFIG
@@ -98,6 +102,30 @@ def test_fp8_gemm_compiles(one_chip):
         x, w, sw, block_m=SLOTS, block_n=128,
         out_dtype=jnp.bfloat16)).lower(x, w, sw).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("C", [8, 104], ids=["decode", "prefill"])
+@pytest.mark.parametrize("proj", ["gate", "down"])
+def test_moe_grouped_gemm_compiles(one_chip, proj, C):
+    """The block-scaled expert GEMM at CONFIG widths, at the served
+    decode capacity (32 slots) and a one-row prefill's: whichever form C
+    picks, the compiled program holds no f32 array of the weight's size."""
+    t = CFG.transformer
+    E = make_moe_spec(t.n_experts, t.top_k, t.d_model, t.d_expert,
+                      ep_degree=t.ep_degree).n_experts_padded
+    K, N = ((t.d_model, t.d_expert) if proj == "gate"
+            else (t.d_expert, t.d_model))
+    x = _spec(one_chip, (E, C, K), jnp.bfloat16)
+    data = _spec(one_chip, (E, K, N), jnp.float8_e4m3fn)
+    scale = _spec(one_chip, (E, K // 128, N // 128), jnp.float32)
+    compiled = jax.jit(lambda x, d, s: fp8_grouped_matmul(
+        x, QuantizedTensor(d, s, "block"))).lower(x, data, scale).compile()
+    # arrays the program writes to memory: the entry computation's results
+    # (a fusion's f32 body stays in registers)
+    entry = compiled.as_text().split("\nENTRY ")[1]
+    sizes = [math.prod(int(n) for n in dims.split(",") if n)
+             for dims in re.findall(r"= f32\[([\d,]*)\]", entry)]
+    assert max(sizes) < E * K * N
 
 
 def test_param_build_fits_one_chip(one_chip):
