@@ -7,7 +7,8 @@
 For each seed it builds the cell's engine with that seed's weights, serves
 a short window of the cell's own traffic at the cell's own load, samples
 the finished requests as a benchmark run does, frees the engine and reads
-the numbers against the plain reference (``reference.readings``).
+the numbers against the family's plain reference
+(``harness.reference_readings``).
 
 ``--control`` seeds also read the control on the same requests, as a run
 puts it in the program's place (``harness.compare``): the reference with
@@ -35,25 +36,26 @@ os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
-
-def serve(conf, mix, seed, seconds):
-    """Build, warm, serve one window; returns the sampled rows and stats."""
+def serve(family, conf, mix, seed, seconds):
+    """Build, warm, serve one window; returns the sampled answers and
+    stats."""
     from bench import harness, traffic
 
-    engine = harness.build_engine(conf, seed)
-    wl = traffic.generate(mix, conf["model"], seed, seconds)
-    harness.warm_up(engine, wl, seed)
+    engine = harness.build_engine(family, conf, seed)
+    wl = traffic.generate(mix, family, conf["model"], seed, seconds)
+    family.warm_up(engine, wl, seed)
     win = harness.Window(engine, wl, seconds, False, None)
     win.run()
-    rows = harness.sample_rows(win.records, wl.requests,
-                               conf["reference"]["sample_requests"], seed)
+    answers = harness.sample_answers(win.records, wl.requests,
+                                     conf["reference"]["sample_requests"],
+                                     seed)
     lat = sorted((r.done - r.due) for r in win.records if r.done is not None)
     info = {"served": len(lat), "failed": win.failed,
             "p95_ms": 1e3 * lat[int(0.95 * (len(lat) - 1))] if lat else None,
             "compiles": win.compiles}
     del engine, win
     gc.collect()
-    return rows, info
+    return answers, info
 
 
 def main() -> int:
@@ -66,38 +68,31 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
-    import numpy as np
 
     if jax.devices()[0].platform != "tpu":
         print("calibrate: no TPU", file=sys.stderr)
         return 2
     from bench import harness
-    from bench.reference import readings, reference_logits
     from repro.launch.serve import enable_compile_cache
 
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     spec = harness.load_json(ROOT / "BENCHMARK.json")
-    cell = harness.find(spec["workloads"], args.workload, "workload")
-    entry = harness.find(spec["configs"], cell["config"], "config")
-    conf = harness.load_json(ROOT / entry["file"])
-    mix = harness.load_json(harness.traffic_path(harness.BENCH,
-                                                 cell["traffic"]))
+    _, conf, mix, family = harness.load_cell(spec, args.workload)
     ints = lambda s: [int(x) for x in s.split(",") if x]
     control, witness = set(ints(args.control)), set(ints(args.witness))
     for seed in ints(args.seeds):
         t0 = time.perf_counter()
-        rows, info = serve(conf, mix, seed, args.seconds)
-        ref = reference_logits(conf["model"], seed, rows)
-        served = np.stack([r[2] for r in rows])
+        answers, info = serve(family, conf, mix, seed, args.seconds)
         out = {"workload": args.workload, "seed": seed, "kind": "program",
-               **readings(ref, served), **info,
+               **harness.reference_readings(family, conf, seed, answers),
+               **info,
                "seconds": time.perf_counter() - t0}
         print(json.dumps(out), flush=True)
         if seed in control:
             logged = []
-            checks = harness.compare(conf, seed, rows, logged.append,
-                                     control=True)
+            checks = harness.compare(family, conf, seed, answers,
+                                     logged.append, control=True)
             print(json.dumps({"workload": args.workload, "seed": seed,
                               "kind": "control", "checks": checks,
                               "logged": logged}), flush=True)
@@ -111,12 +106,12 @@ def main() -> int:
             w_conf["engine"]["n_pages"] = min(w_conf["engine"]["n_pages"],
                                               2000)
             w_conf["engine"]["prefix_rows"] = 100
-            w_rows, w_info = serve(w_conf, mix, seed, args.seconds)
-            w_ref = reference_logits(conf["model"], seed, w_rows)
+            w_answers, w_info = serve(family, w_conf, mix, seed,
+                                      args.seconds)
             print(json.dumps({"workload": args.workload, "seed": seed,
                               "kind": "witness_no_capacity_drops",
-                              **readings(w_ref,
-                                         np.stack([r[2] for r in w_rows])),
+                              **harness.reference_readings(
+                                  family, conf, seed, w_answers),
                               **w_info}), flush=True)
     return 0
 

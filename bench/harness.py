@@ -5,7 +5,10 @@ plain reference.
 
 Everything a cell needs is found by name: ``BENCHMARK.json`` names the
 cell's configuration (``configs/<config>.json``) and traffic mix
-(``traffic/<mix>.json``), and each metric is read by
+(``traffic/<mix>.json``); the configuration names its model family
+(``"family"``, ``onerec`` where it names none), whose module
+``families/<family>.py`` holds all that is particular to the model (see
+``families/onerec.py``); and each metric is read by
 ``metrics/<metric>.py`` (or, for a split metric such as ``mfu.backlog``,
 by the reader of the part before the first dot).
 """
@@ -21,12 +24,14 @@ import sys
 import tempfile
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 BENCH = Path(__file__).resolve().parent
 DRAIN_LIMIT_S = 60.0      # an answer later than this past the close failed
+DEFAULT_FAMILY = "onerec"
 
 
 # -- finding things by name ---------------------------------------------------
@@ -47,18 +52,40 @@ def traffic_path(bench_dir: Path, mix: str) -> Path:
     return bench_dir / "traffic" / f"{mix}.json"
 
 
+def _load(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(bench_dir: Path, name: str) -> Callable:
     """``metrics/<name>.py``, else the reader of ``name``'s part before the
     first dot; the module's ``read(ctx)`` returns a number or None."""
     for stem in (name, name.split(".")[0]):
         path = bench_dir / "metrics" / f"{stem}.py"
         if path.exists():
-            spec = importlib.util.spec_from_file_location(
-                f"bench_metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+            return _load(path, f"bench_metric_{stem.replace('.', '_')}").read
     raise KeyError(f"no reader for metric {name!r} under {bench_dir}/metrics")
+
+
+def load_family(bench_dir: Path, name: str) -> ModuleType:
+    """``families/<name>.py``: the model family's engine, requests,
+    warm-up, call records, reference and costs."""
+    path = bench_dir / "families" / f"{name}.py"
+    if not path.exists():
+        raise KeyError(f"no family {name!r} under {bench_dir}/families")
+    return _load(path, f"bench_family_{name}")
+
+
+def load_cell(spec: dict, cell_name: str, bench_dir: Path = BENCH):
+    """(cell, configuration, traffic mix, family module) of a cell."""
+    cell = find(spec["workloads"], cell_name, "workload")
+    entry = find(spec["configs"], cell["config"], "config")
+    conf = load_json(bench_dir.parent / entry["file"])
+    mix = load_json(traffic_path(bench_dir, cell["traffic"]))
+    family = load_family(bench_dir, conf.get("family", DEFAULT_FAMILY))
+    return cell, conf, mix, family
 
 
 def cell_metrics(spec: dict, cell: str, kind: str) -> List[dict]:
@@ -67,123 +94,15 @@ def cell_metrics(spec: dict, cell: str, kind: str) -> List[dict]:
             if "workloads" not in m or cell in m["workloads"]]
 
 
-# -- the configuration ----------------------------------------------------------
+# -- the engine ---------------------------------------------------------------
 
-def model_config(m: dict):
-    """The engine's ``OneRecConfig`` from a configuration file's ``model``."""
-    from repro.configs.base import OneRecConfig, TransformerConfig
-
-    t_keys = {f.name for f in dataclasses.fields(TransformerConfig)}
-    o_keys = {f.name for f in dataclasses.fields(OneRecConfig)}
-    backbone = TransformerConfig(
-        name=m["backbone_name"],
-        **{k: v for k, v in m.items() if k in t_keys and k != "name"})
-    return OneRecConfig(
-        transformer=backbone,
-        **{k: v for k, v in m.items() if k in o_keys and k != "transformer"})
+def build_engine(family: ModuleType, conf: dict, seed: int):
+    """The served engine of ``conf`` with the weights of ``seed``, as the
+    family builds it (the one place a run builds it)."""
+    return family.build_engine(conf, seed)
 
 
-def engine_config(e: dict):
-    from repro.serving import EngineConfig
-
-    return EngineConfig(
-        batch_size=e["n_slots"], n_slots=e["n_slots"], use_fp8=e["use_fp8"],
-        kv_dtype=e["kv_dtype"], mode="continuous", paged=e["paged"],
-        page_size=e["page_size"], n_pages=e["n_pages"],
-        prefix_cache=e["prefix_cache"], prefix_rows=e["prefix_rows"],
-        fused_decode=e["fused_decode"])
-
-
-def build_engine(conf: dict, seed: int):
-    """The served engine with the benchmark's weights for ``seed``: bf16
-    leaves go in as they are; under a quantizing policy the program's
-    leaf-at-a-time builder quantizes them (the bf16 tree and its fp8 copy
-    do not fit one chip together)."""
-    import jax
-
-    from repro.core.ptq import build_quantized_params
-    from repro.serving import ServingEngine
-    from repro.serving.engine import resolve_quant_policy
-    from bench import weights
-
-    cfg = model_config(conf["model"])
-    ecfg = engine_config(conf["engine"])
-    policy, _ = resolve_quant_policy(ecfg)
-    key = weights.root_key(seed)
-    init = lambda k: weights.init_params(k, conf["model"])
-    if policy.enabled:
-        params = build_quantized_params(init, key, policy)
-    else:
-        params = jax.jit(init)(key)
-    engine = ServingEngine(params, cfg, ecfg)
-    jax.block_until_ready((engine.executor.params, engine.executor.cache))
-    return engine
-
-
-# -- warm-up ------------------------------------------------------------------
-
-def warm_up(engine, workload, seed: int, log=lambda s: None) -> int:
-    """Serve, for every prefill length bucket the traffic's histories fall
-    in, one group of each batch bucket (1, 2, 4 .. slots), so the window
-    finds every prefill, select, decode and page-release program compiled.
-    Returns the number of warm-up requests served."""
-    from repro.serving.executor import bucket_length
-
-    cfg, ex = engine.cfg, engine.executor
-    ncb = cfg.n_codebooks
-    cap = cfg.history_len * ncb
-    rep: Dict[int, int] = {}
-    for items in workload.history_items(ncb):
-        b = min(bucket_length(items * ncb, ex.prefill_bucket_min), cap)
-        rep[b] = max(rep.get(b, 0), items)
-    rng = np.random.default_rng(seed + 1)
-    vocab = cfg.codebook_size
-    served = 0
-    for items in sorted(rep.values()):
-        t0 = time.perf_counter()
-        b = 1
-        while b <= engine.n_slots:
-            for _ in range(b):
-                engine.submit({"tokens": rng.integers(0, vocab,
-                                                      size=items * ncb),
-                               "profile": rng.normal(size=64)})
-            engine.drain()
-            served += b
-            b *= 2
-        log(f"[warm-up] {items}-item histories, groups of 1..{b // 2}: "
-            f"{time.perf_counter() - t0:.3f} s")
-    if "sessions" in workload.mix and engine.prefix_store is not None:
-        # returning users resume from a stored prefix: one resume group of
-        # each batch bucket (their suffix of a few items is one bucket)
-        grow = workload.mix["sessions"]["grow_items"][1]
-        base = min(32, cfg.history_len - grow) * ncb
-        grow *= ncb
-        b = 1
-        while b <= engine.n_slots:
-            hist = [rng.integers(0, vocab, size=base) for _ in range(b)]
-            prof = [rng.normal(size=64) for _ in range(b)]
-            for extra in (0, grow):
-                for h, p in zip(hist, prof):
-                    engine.submit({"tokens": np.concatenate(
-                        [h, rng.integers(0, vocab, size=extra)])
-                        if extra else h, "profile": p})
-                engine.drain()
-            served += 2 * b
-            b *= 2
-    if ex.paged:
-        # retiring requests and evicting stored prefixes release pages in
-        # batches of any size: compile each release bucket (the sentinel
-        # page is already virgin, so the writes change nothing)
-        most = bucket_length(engine.n_slots * ex._p_max, 1)
-        b = 1
-        while b <= most:
-            ex._free_pages_device([ex._sentinel] * b)
-            b *= 2
-    engine.reset_window()
-    return served
-
-
-# -- the window -----------------------------------------------------------------
+# -- the window ---------------------------------------------------------------
 
 @dataclasses.dataclass
 class Record:
@@ -195,14 +114,13 @@ class Record:
 
 class Recorder:
     """Host records of the prefill and decode calls the engine makes while
-    the traced window is open (real tokens per row, decode positions)."""
+    the traced window is open (real tokens per row, decode positions):
+    ``calls`` maps an executor method to the family's record of a call."""
 
-    def __init__(self, executor):
+    def __init__(self, executor, calls: Dict[str, Callable]):
         self.on = False
         self.calls: List[dict] = []
-        for name, describe in (("prefill_insert", self._prefill),
-                               ("resume_prefill", self._resume),
-                               ("decode", self._decode)):
+        for name, describe in calls.items():
             setattr(executor, name, self._wrap(getattr(executor, name),
                                                describe))
 
@@ -212,22 +130,6 @@ class Recorder:
                 self.calls.append(describe(*args, **kw))
             return fn(*args, **kw)
         return inner
-
-    @staticmethod
-    def _prefill(tokens_list, profiles, slots):
-        return {"kind": "prefill",
-                "tokens": [len(t) + 1 for t in tokens_list],
-                "starts": [0] * len(tokens_list)}
-
-    @staticmethod
-    def _resume(tokens_list, slots, starts):
-        return {"kind": "resume", "tokens": [len(t) for t in tokens_list],
-                "starts": [int(s) for s in starts]}
-
-    @staticmethod
-    def _decode(tokens, lengths):
-        return {"kind": "decode",
-                "positions": [int(p) for p in np.asarray(lengths) if p > 0]}
 
 
 class Window:
@@ -374,10 +276,10 @@ class Window:
 
 # -- the run ------------------------------------------------------------------
 
-def sample_rows(records: List[Record], requests: List[dict], n: int,
-                seed: int) -> List[tuple]:
+def sample_answers(records: List[Record], requests: List[dict], n: int,
+                   seed: int) -> List[tuple]:
     """Up to ``n`` finished requests drawn from ``seed``, the longest
-    history among them: (tokens, profile, served item)."""
+    prompt among them: (request, served answer)."""
     done = [i for i, r in enumerate(records) if r.item is not None]
     if not done:
         return []
@@ -386,30 +288,38 @@ def sample_rows(records: List[Record], requests: List[dict], n: int,
     rng = np.random.default_rng(seed + 2)
     pick = [longest] + list(rng.choice(rest, size=min(n - 1, len(rest)),
                                        replace=False))
-    return [(requests[i]["tokens"], requests[i]["profile"], records[i].item)
-            for i in pick]
+    return [(requests[i], records[i].item) for i in pick]
 
 
-def compare(conf: dict, seed: int, rows: List[tuple], log,
-            control: bool = False) -> Dict[str, dict]:
+def reference_readings(family: ModuleType, conf: dict, seed: int,
+                       answers: List[tuple],
+                       control: bool = False) -> Dict[str, float]:
+    """``reference.readings`` of the served tokens of ``answers`` against
+    the family's plain reference.  With ``control`` the tokens judged are
+    not the served ones but those the control puts first at each served
+    position: the reference with its quantized weights rounded to
+    ``control_weight_bits``."""
+    from bench.reference import readings
+
+    rows = [family.reference_row(req, item) for req, item in answers]
+    ref = family.reference_logits(conf["model"], seed, rows)
+    if control:
+        tokens = family.reference_logits(
+            conf["model"], seed, rows,
+            weight_bits=conf["reference"]["control_weight_bits"]).argmax(-1)
+    else:
+        tokens = np.stack([family.answer_tokens(item)
+                           for _, item in answers])
+    return readings(ref, tokens)
+
+
+def compare(family: ModuleType, conf: dict, seed: int, answers: List[tuple],
+            log, control: bool = False) -> Dict[str, dict]:
     """The numbers that decide ``correct``, each with its limit (those the
-    configuration's ``limits`` name); the others are logged.  With
-    ``control`` the tokens judged are not the served ones but those the
-    control puts first at each served position: the reference with its
-    quantized weights rounded to ``control_weight_bits``."""
-    from bench.reference import readings, reference_logits
-
+    configuration's ``limits`` name); the others are logged."""
     limits = conf["limits"]
-    if rows:
-        ref = reference_logits(conf["model"], seed, rows)
-        if control:
-            tokens = reference_logits(
-                conf["model"], seed, rows,
-                weight_bits=conf["reference"]["control_weight_bits"]
-            ).argmax(-1)
-        else:
-            tokens = np.stack([r[2] for r in rows])
-        got = readings(ref, tokens)
+    if answers:
+        got = reference_readings(family, conf, seed, answers, control)
     else:
         got = {k: float("inf") for k in limits}
     for k, v in got.items():
@@ -429,10 +339,12 @@ class Context:
     build_s: float
     latencies_ms: List[float]     # every request due in the window
     items_in_window: int
-    history_tokens: int           # history tokens submitted in the window
+    history_tokens: int           # prompt tokens submitted in the window
     stats: dict                   # engine stats at the close
     calls: List[dict]             # host records of the traced calls
     trace: object                 # bench.trace.Trace or None
+    costs: ModuleType             # the family: prefill_flops, decode_flops,
+                                  # paged_decode_cost of ``model``
 
 
 def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
@@ -448,24 +360,21 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
     from bench import trace as trace_mod
     from bench import traffic
 
-    cell = find(spec["workloads"], cell_name, "workload")
-    conf_entry = find(spec["configs"], cell["config"], "config")
-    conf = load_json(bench_dir.parent / conf_entry["file"])
-    mix = load_json(traffic_path(bench_dir, cell["traffic"]))
+    _, conf, mix, family = load_cell(spec, cell_name, bench_dir)
     devs = jax.devices()
     dev = devs[0]
     peaks = (peak_table.peaks_for(dev.device_kind)
              if dev.platform == "tpu" else {})
 
     t0 = time.perf_counter()
-    engine = build_engine(conf, seed)
+    engine = build_engine(family, conf, seed)
     build_s = time.perf_counter() - t0
     built = (dev.memory_stats() or {}).get("bytes_in_use")
-    workload = traffic.generate(mix, conf["model"], seed, seconds)
+    workload = traffic.generate(mix, family, conf["model"], seed, seconds)
     log(f"[setup] {cell_name}: engine built in {build_s:.3f} s, "
         f"bytes_in_use {built}")
-    n_warm = warm_up(engine, workload, seed, log)
-    recorder = Recorder(engine.executor) if trace else None
+    n_warm = family.warm_up(engine, workload, seed, log)
+    recorder = Recorder(engine.executor, family.CALLS) if trace else None
     window = Window(engine, workload, seconds, trace, recorder)
     setup_s = time.perf_counter() - t_start
     log(f"[setup] {cell_name}: build {build_s:.3f} s, {n_warm} warm-up "
@@ -506,7 +415,8 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
                   seconds=seconds, setup_s=setup_s, build_s=build_s,
                   latencies_ms=latencies, items_in_window=items,
                   history_tokens=hist, stats=stats,
-                  calls=recorder.calls if recorder else [], trace=tr)
+                  calls=recorder.calls if recorder else [], trace=tr,
+                  costs=family)
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in cell_metrics(spec, cell_name, kind):
@@ -515,13 +425,13 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
     # the reference runs once the program's state is freed
-    rows = sample_rows(recs, workload.requests,
-                       conf["reference"]["sample_requests"], seed)
+    answers = sample_answers(recs, workload.requests,
+                             conf["reference"]["sample_requests"], seed)
     del engine, window, recorder
     gc.collect()
     log(f"[reference] bytes_in_use once the engine is freed: "
         f"{(dev.memory_stats() or {}).get('bytes_in_use')}")
-    checks = compare(conf, seed, rows, log, control)
+    checks = compare(family, conf, seed, answers, log, control)
     correct = all(c["value"] <= c["limit"] for c in checks.values())
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
@@ -537,8 +447,9 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
         result["breakdown"] = {"device_ops": trace_mod.top_ops(tr),
                                "idle_gaps": trace_mod.idle_gaps(tr)}
     result["checks"] = checks
+    n_tokens = sum(np.size(family.answer_tokens(item))
+                   for _, item in answers)
     for name, c in checks.items():
         log(f"[check] {name} {c['value']!r} limit {c['limit']!r} "
-            f"({len(rows)} requests, {len(rows) * conf['model']['decode_len']}"
-            f" served tokens)")
+            f"({len(answers)} requests, {n_tokens} served tokens)")
     return result

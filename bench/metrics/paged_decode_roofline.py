@@ -1,9 +1,10 @@
 """The paged-decode attention kernel's share of its roofline: the least
 time its calls in the traced window could take (the larger of bytes over
 HBM bandwidth and operations over the bf16 peak, per call, from the page
-tables of each decode step) over the kernel's device time in the trace."""
+tables of each decode step, by the family's ``ctx.costs``) over the
+kernel's device time in the trace."""
 
-from bench import costs, trace
+from bench import trace
 
 KERNEL = r"paged_decode|decode_kernel"
 
@@ -18,7 +19,7 @@ def read(ctx):
     for c in ctx.calls:
         if c["kind"] != "decode":
             continue
-        flops, nbytes = costs.paged_decode_cost(
+        flops, nbytes = ctx.costs.paged_decode_cost(
             ctx.model, ctx.engine["kv_dtype"], ctx.engine["page_size"],
             c["positions"])
         least += max(nbytes / ctx.peaks["hbm_bytes_per_s"],

@@ -49,20 +49,16 @@ def main() -> int:
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     spec = harness.load_json(ROOT / "BENCHMARK.json")
-    cell = harness.find(spec["workloads"], args.workload, "workload")
-    entry = harness.find(spec["configs"], cell["config"], "config")
-    conf = harness.load_json(ROOT / entry["file"])
-    mix = harness.load_json(harness.traffic_path(harness.BENCH,
-                                                 cell["traffic"]))
+    _, conf, mix, family = harness.load_cell(spec, args.workload)
     rates = [float(r) for r in args.rates.split(",")]
     knee, broken = None, False
     for i, rate in enumerate(sorted(rates)):
         # a fresh engine for each rate, as in a benchmark run: the prefix
         # store starts with the warm-up's histories only
-        engine = harness.build_engine(conf, args.seed + i)
-        wl = traffic.generate(dict(mix, rate_per_s=rate), conf["model"],
-                              args.seed + i, args.seconds)
-        harness.warm_up(engine, wl, args.seed + i)
+        engine = harness.build_engine(family, conf, args.seed + i)
+        wl = traffic.generate(dict(mix, rate_per_s=rate), family,
+                              conf["model"], args.seed + i, args.seconds)
+        family.warm_up(engine, wl, args.seed + i)
         win = harness.Window(engine, wl, args.seconds, False, None)
         win.run()
         recs = win.records

@@ -1,6 +1,7 @@
 """A tiny copy of the benchmark for CPU tests: the reduced onerec-v2
 widths, a bench directory of its own under ``tmp_path`` (the real metric
-readers, tiny traffic mixes), and a spec naming a few tiny cells."""
+readers and families, tiny traffic mixes), and a spec naming a few tiny
+cells."""
 
 from __future__ import annotations
 
@@ -15,16 +16,23 @@ BENCH = Path(__file__).resolve().parents[1]
 DATA = Path(__file__).resolve().parent / "data"
 
 
-def tiny_conf(**engine) -> dict:
-    conf = json.loads((DATA / "onerec-tiny.json").read_text())
+def tiny_conf(name: str = "onerec-tiny", **engine) -> dict:
+    conf = json.loads((DATA / f"{name}.json").read_text())
     conf["engine"].update(engine)
     return conf
+
+
+def family(name: str = "onerec"):
+    from bench.harness import load_family
+
+    return load_family(BENCH, name)
 
 
 def tiny_bench(tmp_path: Path, conf: dict, extra_cells=()):
     """(spec, bench_dir) of a benchmark whose cells serve ``conf``."""
     bd = tmp_path / "bench"
     shutil.copytree(BENCH / "metrics", bd / "metrics")
+    shutil.copytree(BENCH / "families", bd / "families")
     (bd / "traffic").mkdir()
     (bd / "configs").mkdir()
     for mix in ("tiny-open", "tiny-closed", "tiny-revisit"):
@@ -41,7 +49,9 @@ def tiny_bench(tmp_path: Path, conf: dict, extra_cells=()):
         {"name": "tiny.revisit", "config": "tiny",
          "traffic": "tiny-revisit", "chips": 1}] + list(extra_cells)
     for m in spec["end_to_end"] + spec["per_layer"]:
-        if "workloads" in m:
+        if m.get("moves") == "setup_s":
+            m.pop("workloads", None)    # set-up: every cell reports it
+        elif "workloads" in m:
             m["workloads"] = ["tiny.open"]
     # a closed-loop cell reports throughput and its own split metrics
     closed = {"workloads": ["tiny.closed"], "source": "host_clock"}

@@ -20,8 +20,8 @@ def bench(tmp_path_factory):
 def _broken(monkeypatch, breaker):
     build = harness.build_engine
 
-    def build_broken(conf, seed):
-        engine = build(conf, seed)
+    def build_broken(family, conf, seed):
+        engine = build(family, conf, seed)
         breaker(engine.executor)
         return engine
 

@@ -7,10 +7,10 @@ import numpy as np
 import pytest
 
 from bench import harness
-from bench.reference import reference_logits
 from bench.tests import helpers
 
 MODEL = helpers.tiny_conf()["model"]
+ONEREC = helpers.family()
 
 
 def teacher_forced(engine, requests, served):
@@ -36,14 +36,14 @@ def teacher_forced(engine, requests, served):
 def test_reference_matches_prefill_and_paged_decode(fused):
     conf = helpers.tiny_conf(use_fp8=False, kv_dtype="bfloat16",
                              fused_decode=fused)
-    engine = harness.build_engine(conf, 7)
+    engine = harness.build_engine(ONEREC, conf, 7)
     rng = np.random.default_rng(0)
     reqs = [{"tokens": rng.integers(0, 192, size=3 * k),
              "profile": rng.normal(size=64)} for k in (2, 5, 8, 8)]
     served, _ = engine.serve_requests(reqs)
     got = teacher_forced(engine, reqs, served)
-    ref = reference_logits(MODEL, 7, [(r["tokens"], r["profile"], s)
-                                      for r, s in zip(reqs, served)])
+    ref = ONEREC.reference_logits(MODEL, 7, [(r["tokens"], r["profile"], s)
+                                             for r, s in zip(reqs, served)])
     # bf16 weights, activations and KV against f32: ~1% of the logits'
     # scale (max |logit| ~4 here)
     assert np.abs(got - ref).max() < 0.1

@@ -1,12 +1,13 @@
 """The one traffic generator: reads a mix's parameters (``traffic/<mix>.json``)
-and turns them into requests from ``--seed``.
+and turns them into requests from ``--seed``, through the cell's family
+(``families/<family>.py``), which makes each request's content.
 
-Every seed gets the same schedule: the same history lengths and the same
+Every seed gets the same schedule: the same request sizes and the same
 due times, in the same order, drawn from the mix's own ``mix_seed``.  So a
-seed changes which user sends what (token ids and profiles come from the
-seed alone), and neither how much work a run holds nor when it arrives:
-under Poisson arrivals near the knee, the order of the gaps alone moves a
-run's tail by tens of percent.
+seed changes which user sends what (the content comes from the seed
+alone), and neither how much work a run holds nor when it arrives: under
+Poisson arrivals near the knee, the order of the gaps alone moves a run's
+tail by tens of percent.
 
 Mix parameters:
 
@@ -14,14 +15,11 @@ Mix parameters:
   drawn as quantiles, so a window of ``s`` seconds holds
   ``round(rate_per_s * s)`` requests, all due inside it) or ``"closed"``
   (``outstanding`` requests in flight; each completion sends the next).
-- ``lengths``: fresh histories; ``cap_share`` of them at ``cap_items``, the
-  rest log-uniform over ``[min_items, max_items]`` (not needed where
-  ``cap_share`` is 1).
-- ``sessions`` (instead of ``lengths``): ``users`` returning users with
-  Zipf(``zipf``) popularity; each starts with ``start_items`` (a range)
-  and grows by ``grow_items`` (a range) at each visit, up to the cap.  The
-  visit sequence comes from ``mix_seed``, the users' content from
-  ``--seed``.
+- ``lengths``: fresh requests, sized in the family's unit (OneRec: history
+  items); ``cap_share`` of them at ``cap_items``, the rest log-uniform over
+  ``[min_items, max_items]`` (not needed where ``cap_share`` is 1).
+- ``sessions`` (instead of ``lengths``): returning users, whose visits the
+  family draws (``sessions(mix, model, n, rng)``).
 - ``mix_seed``: the schedule's seed.
 """
 
@@ -32,8 +30,6 @@ from typing import Dict, List
 
 import numpy as np
 
-PROFILE_DIM = 64
-
 
 def _shuffled(values, rng) -> list:
     values = list(values)
@@ -42,7 +38,8 @@ def _shuffled(values, rng) -> list:
 
 
 def fresh_items(lengths: dict, n: int) -> List[int]:
-    """The fixed multiset of ``n`` history lengths, in items (sorted)."""
+    """The fixed multiset of ``n`` request sizes, in the family's unit
+    (sorted)."""
     n_cap = int(round(lengths["cap_share"] * n))
     if n_cap == n:
         return [lengths["cap_items"]] * n
@@ -76,20 +73,13 @@ class Workload:
     def closed(self) -> bool:
         return self.mix["arrival"] == "closed"
 
-    def history_items(self, n_codebooks: int) -> List[int]:
-        return [len(r["tokens"]) // n_codebooks for r in self.requests]
 
-
-def _request(tokens, profile) -> Dict:
-    return {"tokens": np.asarray(tokens, np.int32),
-            "profile": np.asarray(profile, np.float32)}
-
-
-def generate(mix: dict, model: dict, seed: int, seconds: float) -> Workload:
-    """The requests of one run of ``seconds`` under ``mix``."""
+def generate(mix: dict, family, model: dict, seed: int,
+             seconds: float) -> Workload:
+    """The requests of one run of ``seconds`` under ``mix``; ``family``
+    makes their content for ``model``."""
     rng = np.random.default_rng(seed)
     sched = np.random.default_rng(mix["mix_seed"])
-    ncb, vocab = model["n_codebooks"], model["codebook_size"]
     if mix["arrival"] == "poisson":
         n = max(int(round(mix["rate_per_s"] * seconds)), 1)
     elif mix["arrival"] == "closed":
@@ -100,46 +90,13 @@ def generate(mix: dict, model: dict, seed: int, seconds: float) -> Workload:
         raise ValueError(f"unknown arrival {mix['arrival']!r}")
 
     if "sessions" in mix:
-        requests = _sessions(mix, model, n, rng)
+        requests = family.sessions(mix, model, n, rng)
     else:
-        items = _shuffled(fresh_items(mix["lengths"], n), sched)
-        requests = [_request(rng.integers(0, vocab, size=k * ncb),
-                             rng.normal(size=PROFILE_DIM))
-                    for k in items]
+        sizes = _shuffled(fresh_items(mix["lengths"], n), sched)
+        requests = [family.fresh_request(model, k, rng) for k in sizes]
     due = []
     if mix["arrival"] == "poisson":
         due = list(np.cumsum(_shuffled(
             poisson_gaps(mix["rate_per_s"], n, seconds), sched)))
     return Workload(requests, [float(d) for d in due], mix)
 
-
-def _sessions(mix: dict, model: dict, n: int, rng) -> List[Dict]:
-    """Visits of returning users.  The visit sequence (who comes back and
-    by how much the history grows) is drawn from ``mix_seed``, so every
-    seed does the same work; users' items and profiles come from ``rng``."""
-    s = mix["sessions"]
-    ncb, vocab = model["n_codebooks"], model["codebook_size"]
-    cap = model["history_len"]
-    fixed = np.random.default_rng(mix["mix_seed"])
-    ranks = np.arange(1, s["users"] + 1, dtype=np.float64)
-    p = ranks ** -s["zipf"]
-    p /= p.sum()
-    lo, hi = s["start_items"]
-    start = [int(round(lo + (hi - lo) * (j + 0.5) / s["users"]))
-             for j in range(s["users"])]
-    start = list(fixed.permutation(start))
-    g_lo, g_hi = s["grow_items"]
-    visits = fixed.choice(s["users"], size=n, p=p)
-    grows = fixed.integers(g_lo, g_hi + 1, size=n)
-    hist = [None] * s["users"]
-    profiles = [None] * s["users"]
-    requests = []
-    for u, g in zip(visits, grows):
-        if hist[u] is None:
-            hist[u] = list(rng.integers(0, vocab, size=start[u] * ncb))
-            profiles[u] = rng.normal(size=PROFILE_DIM)
-        else:
-            room = cap * ncb - len(hist[u])
-            hist[u] += list(rng.integers(0, vocab, size=min(g * ncb, room)))
-        requests.append(_request(hist[u], profiles[u]))
-    return requests
